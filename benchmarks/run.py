@@ -41,6 +41,8 @@ def main(argv=None) -> None:
     ap.add_argument("--batch-sizes", default="1,4",
                     help="multi-RHS sweep for the JSON payload")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable as enable_compile_cache
+    enable_compile_cache()
 
     from . import bench_kernels, bench_pcg, bench_spmv, bench_sptrsv
 

@@ -100,17 +100,10 @@ __all__ = ["AzulEngine", "local_sptrsv"]
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: ``jax.shard_map`` (check_vma) on
-    current releases, ``jax.experimental.shard_map`` (check_rep) on older
-    ones -- both with replication checking off (the solver programs emit
-    psum'd scalars whose replication the checker cannot always prove)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with replication checking off: the solver programs
+    emit psum'd scalars whose replication the checker cannot always prove."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

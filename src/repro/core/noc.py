@@ -46,14 +46,6 @@ def axis_coord(axis: str) -> jnp.ndarray:
     return lax.axis_index(axis)
 
 
-def _axis_size(axis) -> int:
-    """Static size of a (tuple of) mesh axis -- ``lax.axis_size`` where it
-    exists, otherwise the classic eager ``psum(1, axis)`` trick."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return int(lax.psum(1, axis))
-
-
 def _ppermute(x: jnp.ndarray, axes, perm) -> jnp.ndarray:
     """ppermute that elides identity permutations (p == 1 axes, zero
     shifts): the NoC hop disappears instead of becoming a no-op message."""
@@ -64,7 +56,7 @@ def _ppermute(x: jnp.ndarray, axes, perm) -> jnp.ndarray:
 
 def neighbor_shift(x: jnp.ndarray, axis: str, shift: int = 1) -> jnp.ndarray:
     """One torus hop along ``axis`` (wraps around) -- a single Azul send."""
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return _ppermute(x, axis, perm)
 
@@ -75,7 +67,7 @@ def pull_shard(x: jnp.ndarray, axes, delta: int) -> jnp.ndarray:
     pull schedule (:mod:`repro.core.commplan`); identity hops (p == 1,
     delta % p == 0) emit no ppermute."""
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
-    p = _axis_size(axes)
+    p = lax.axis_size(axes)
     perm = [((i + delta) % p, i) for i in range(p)]
     return _ppermute(x, axes, perm)
 
@@ -120,8 +112,8 @@ def mesh_transpose(x: jnp.ndarray, row_axes, col_axes) -> jnp.ndarray:
     """
     row_axes = (row_axes,) if isinstance(row_axes, str) else tuple(row_axes)
     col_axes = (col_axes,) if isinstance(col_axes, str) else tuple(col_axes)
-    pr = _axis_size(row_axes)
-    pc = _axis_size(col_axes)
+    pr = lax.axis_size(row_axes)
+    pc = lax.axis_size(col_axes)
     # src tile holds segment q (flat id q = i*pc + j); dest tile for segment
     # q = j*pr + k is (k, j) = flat k*pc + j.  Degenerate grids (pr == 1 or
     # pc == 1, incl. the single-tile mesh) make this the identity -- elided.
@@ -139,7 +131,7 @@ def reverse_vector(x: jnp.ndarray, axes, vec_axis: int = 0) -> jnp.ndarray:
     reverses each RHS, not the batch).  p == 1 reduces to the local flip
     alone -- no ppermute."""
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
-    p = _axis_size(axes)
+    p = lax.axis_size(axes)
     perm = [(p - 1 - q, q) for q in range(p)]
     return jnp.flip(_ppermute(x, axes, perm), axis=vec_axis)
 

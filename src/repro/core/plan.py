@@ -276,6 +276,7 @@ class SolvePlan:
         self.info = info
         self._trace_cell = trace_cell
         self.executions = 0
+        self._exe = None
         self.last_iters = None
         self.last_status = None
         self.last_bad_iter = None
@@ -329,6 +330,33 @@ class SolvePlan:
                 "matching batch"
             )
 
+    def _operands(self, b, x0, vals):
+        """The program's device operands for host (b, x0[, vals])."""
+        eng = self.engine
+        args = (eng.to_device_vec(b), eng.to_device_vec(x0))
+        if self.spec.injectable:
+            args += (eng.vals_operand(vals),)
+        elif vals is not None:
+            raise ValueError(
+                "this plan closes over the matrix values as constants; "
+                "build the spec with injectable=True to pass vals per call")
+        return args
+
+    def compile(self):
+        """Lower and compile the program once; returns the compiled
+        executable (``.as_text()`` shows what the backend runs).  Every
+        execution runs this executable, so a lowering or compile failure
+        surfaces here, before anything executes -- callers that retry
+        runtime faults (the serving layer) compile first and let such
+        failures propagate."""
+        if self._exe is None:
+            shape = ((self.engine.n,) if self.spec.batch is None
+                     else (self.spec.batch, self.engine.n))
+            zeros = np.zeros(shape)
+            self._exe = self._fn.lower(
+                *self._operands(zeros, zeros, None)).compile()
+        return self._exe
+
     def __call__(self, b, x0=None, vals=None):
         """Execute: returns (x, res_norms) as numpy, mirroring the RHS
         shape; per-RHS iteration counts land in ``self.last_iters``,
@@ -349,13 +377,7 @@ class SolvePlan:
                 # so b and x0 agree on the batched sharding spec
                 x0 = np.broadcast_to(x0, b.shape)
         eng = self.engine
-        args = (eng.to_device_vec(b), eng.to_device_vec(x0))
-        if self.spec.injectable:
-            args += (eng.vals_operand(vals),)
-        elif vals is not None:
-            raise ValueError(
-                "this plan closes over the matrix values as constants; "
-                "build the spec with injectable=True to pass vals per call")
+        args = self._operands(b, x0, vals)
         if _obs_enabled():
             # host-side timing only: block_until_ready on the outputs we
             # were about to convert to numpy anyway -- the traced program
@@ -366,7 +388,7 @@ class SolvePlan:
             tr0 = self._trace_cell[0]
             t0 = _clock.now()
             with _span("solve", kind="solve", method=self.spec.method):
-                out = self._fn(*args)
+                out = self.compile()(*args)
                 jax.block_until_ready(out)
             dt = _clock.now() - t0
             traced = self._trace_cell[0] - tr0
@@ -379,7 +401,7 @@ class SolvePlan:
             else:
                 _M_SOLVE_S.observe(dt, method=self.spec.method)
         else:
-            out = self._fn(*args)
+            out = self.compile()(*args)
         x, norms, its, status, bad = out
         self.executions += 1
         self.last_iters = np.asarray(its)

@@ -233,21 +233,23 @@ def resolve_fused(sdef: SolverDef, pdef: PrecondDef, local: bool, knob) -> bool:
     bool: 'auto' and True mean "fused wherever this (method, precond, mode)
     supports it" -- a registry capability lookup, not a name ladder.
 
-    'auto' additionally defers to the backend for preconditioners marked
-    ``fused_local_needs_kernels``: their local fused substrate trades
-    on-chip compute for HBM traffic, a trade that only pays where the
-    Pallas kernels actually dispatch -- on CPU (kernels inactive) the
-    reference apply is faster, so capability resolution prefers it.
-    ``True`` remains an explicit override."""
+    'auto' additionally defers to the kernels for preconditioners marked
+    ``fused_local_needs_kernels``: their local fused substrate (the
+    whole-solve ``sptrsv_solve_dot`` kernel) trades on-chip compute for
+    HBM traffic, a trade that only pays where that kernel actually runs.
+    With kernels inactive (CPU) the reference apply is faster, and on TPU
+    the kernel does not compile (``ops.UNCOMPILABLE``), so capability
+    resolution prefers the reference apply in both cases.  ``True``
+    remains an explicit override."""
     if knob not in ("auto", True, False):
         raise ValueError(f"fused must be 'auto', True or False, got {knob!r}")
     caps = sdef.fused_local if local else sdef.fused_dist
     supported = pdef.name in caps
     if (knob == "auto" and supported and local and sdef.fused_precond_apply
             and pdef.fused_local_needs_kernels):
-        from ..kernels.ops import kernels_active
+        from ..kernels.ops import kernel_usable
 
-        supported = kernels_active()
+        supported = kernel_usable("sptrsv_solve_dot")
     return supported if knob in ("auto", True) else False
 
 
@@ -500,7 +502,8 @@ register_precond(PrecondDef(
     fused_local_kind="fused_ic0", fused_shard_kind="fused_shard_ic0",
     # the whole-solve SpTRSV substrate buys HBM traffic with VPU work --
     # ~7x SLOWER than the reference apply on CPU (BENCH_pcg tol_solves at
-    # lap2d_32), so 'auto' only picks it where kernels dispatch
+    # lap2d_32) and not compilable for TPU, so 'auto' only picks it where
+    # the kernel runs (interpret mode)
     fused_local_needs_kernels=True,
     local_apply=_block_ic0_apply,
 ))

@@ -31,12 +31,14 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = [
     "Stencil",
     "lap2d_stencil",
     "lap3d_stencil",
     "stencil_matvec",
+    "stencil_matvec_host",
     "stencil_diag",
 ]
 
@@ -127,3 +129,20 @@ def stencil_matvec(st: Stencil, x: jnp.ndarray, n_pad: int | None = None) -> jnp
         return y
     out = jnp.zeros(lead + (n_pad,), y.dtype)
     return out.at[..., :n].set(y)
+
+
+def stencil_matvec_host(st: Stencil, x) -> np.ndarray:
+    """float64 numpy oracle of :func:`stencil_matvec` on unpadded (n,) or
+    (k, n) vectors -- the host reference device solves are checked
+    against (a million-row stencil has no assembled CSR to hand scipy)."""
+    x = np.asarray(x, np.float64)
+    lead = x.shape[:-1]
+    u = x.reshape(lead + st.dims)
+    y = stencil_diag(st) * u
+    for ax in range(len(lead), u.ndim):
+        lo = [slice(None)] * u.ndim
+        hi = [slice(None)] * u.ndim
+        lo[ax], hi[ax] = slice(0, -1), slice(1, None)
+        y[tuple(lo)] -= u[tuple(hi)]
+        y[tuple(hi)] -= u[tuple(lo)]
+    return y.reshape(x.shape)

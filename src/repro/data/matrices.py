@@ -12,7 +12,7 @@ import scipy.sparse as sp
 from ..core.formats import CSR, csr_from_scipy
 
 __all__ = ["laplacian_2d", "laplacian_3d", "banded_spd", "random_spd",
-           "rmat_spd", "skew_spd", "suite"]
+           "rmat_spd", "skew_spd", "suite", "named"]
 
 
 def laplacian_2d(nx: int, ny: int | None = None) -> CSR:
@@ -125,3 +125,20 @@ def suite(scale: str = "small") -> dict[str, CSR]:
         "skew_10k": skew_spd(10_000, hubs=16, seed=3),
         "rmat_8k": rmat_spd(8000, 8.0, seed=4),
     }
+
+
+def named(name: str) -> CSR:
+    """A stored operator by name: a generated Laplacian of any size,
+    ``lap2d_<N>`` (N x N grid, n = N**2) or ``lap3d_<N>`` (n = N**3), or a
+    :func:`suite` member (small suite first, then large)."""
+    kind, _, size = name.partition("_")
+    gen = {"lap2d": laplacian_2d, "lap3d": laplacian_3d}.get(kind)
+    if gen is not None and size.isdigit():
+        return gen(int(size))
+    for scale in ("small", "large"):
+        mats = suite(scale)
+        if name in mats:
+            return mats[name]
+    raise ValueError(
+        f"unknown matrix {name!r}: expected lap2d_<N>, lap3d_<N> or one of "
+        f"{sorted(suite('small')) + sorted(suite('large'))}")

@@ -13,7 +13,9 @@ by the CI bench smoke job to exercise kernels on CPU runners).
 Tile selection: explicit tile args always win; otherwise the wrappers
 consult the autotune cache (``autotune.py``, populated by
 ``bench_kernels --autotune``) for this op/shape/dtype/backend, and finally
-fall back to the kernel defaults clamped to valid divisors of the shape.
+fall back to the kernel defaults: lane-axis tiles (rows, vector length) are
+multiples of 128 sized to a VMEM budget, or the whole axis when it is
+short; ELL width tiles are divisors of the width in multiples of 8.
 """
 
 from __future__ import annotations
@@ -38,13 +40,14 @@ from .sptrsv import DEFAULT_TL
 from .vecops import axpy_dot as _axpy_dot_pallas
 from .vecops import cg_update as _cg_update_pallas
 from .vecops import DEFAULT_TN
+from .spmv_dot import LANES
 
 __all__ = [
     "ell_spmv", "ell_spmm", "ell_spmv_dot", "ell_spmm_dot", "bcsr_spmm",
     "ell_spmv_pfold_dot", "ell_spmm_pfold_dot",
     "sptrsv_level_step", "sptrsv_solve_dot", "sptrsv_solve_pack",
     "axpy_dot", "cg_update",
-    "backend_mode", "kernels_active",
+    "backend_mode", "kernels_active", "kernel_usable",
 ]
 
 _MODE = os.environ.get("REPRO_KERNEL_MODE", "auto")
@@ -77,6 +80,20 @@ def kernels_active() -> bool:
     return _dispatch()[0]
 
 
+# Kernels the TPU compiler refuses at real sizes (an AOT compile for v5e
+# rejects their rank-1 level blocks and in-kernel 1-D gathers); the main-path
+# kernels that do compile are guarded by tests/test_tpu_aot.py.  These run
+# in interpret mode only, for semantic validation.
+UNCOMPILABLE = frozenset({"sptrsv_level_step", "sptrsv_solve_dot"})
+
+
+def kernel_usable(op: str) -> bool:
+    """True when ``op`` dispatches to a kernel that can actually run here:
+    kernels are active and either interpreted or compilable."""
+    use, interp = _dispatch()
+    return use and (interp or op not in UNCOMPILABLE)
+
+
 def _fit(total: int, pref: int, quantum: int = 1) -> int:
     """Largest divisor of ``total`` that is <= pref (preferring multiples of
     ``quantum``) -- clamps a preferred tile to a valid one for the shape."""
@@ -90,19 +107,41 @@ def _fit(total: int, pref: int, quantum: int = 1) -> int:
     return total
 
 
-def _tiles_2d(op: str, cols, dtype, tm, tw):
-    """Resolve (tm, tw) for an ELL-shaped kernel.  Explicit args pass
-    through untouched (the kernel raises on invalid tiles -- callers pin
-    tiles deliberately, e.g. for VMEM budgets or autotune candidates);
-    missing args come from the autotune cache, else clamped defaults."""
+# VMEM the picked row/vector tiles may fill (double-buffered): half of the
+# 16 MiB scoped default on TPU v5e, leaving room for the compiler
+VMEM_BUDGET = 8 << 20
+
+
+def _lane_tile(total: int, pref: int, words_per_lane: int = 1,
+               itemsize: int = 4) -> int:
+    """Tile along a lane axis of length ``total``: the whole axis when it
+    fits ``pref``, else a multiple of 128 no larger than ``pref`` nor than
+    the VMEM budget allows at ``words_per_lane`` words per position (the
+    last tile may be ragged: kernels clip and mask it)."""
+    cap = max(LANES, VMEM_BUDGET // (words_per_lane * itemsize))
+    pref = min(pref, cap)
+    if total <= pref:
+        return total
+    return max(LANES, pref // LANES * LANES)
+
+
+def _tiles_2d(op: str, cols, dtype, tm, tw, k: int = 1):
+    """Resolve (tm, tw) for an ELL-shaped kernel streaming k vectors.
+    Explicit args pass through untouched (callers pin tiles deliberately,
+    e.g. for VMEM budgets or autotune candidates); missing args come from
+    the autotune cache, else the defaults: tw a divisor of the width in
+    multiples of 8, tm a lane tile sized to the VMEM budget."""
     rows_p, w = cols.shape
     hit = None
     if tm is None or tw is None:
         hit = autotune.lookup(op, (rows_p, w), dtype) or {}
-    if tm is None:
-        tm = _fit(rows_p, hit.get("tm") or DEFAULT_TM, 8)
     if tw is None:
         tw = _fit(w, hit.get("tw") or DEFAULT_TW, 8)
+    if tm is None:
+        # vals + k gathered planes (tw words each), y + x row tiles
+        words = 2 * (tw * (1 + k) + 2 * k)
+        tm = _lane_tile(rows_p, hit.get("tm") or DEFAULT_TM, words,
+                        jnp.dtype(dtype).itemsize)
     return tm, tw
 
 
@@ -118,7 +157,7 @@ def ell_spmm(cols, vals, x, tm: int | None = None, tw: int | None = None):
     """Multi-RHS SpMM; x is (n, k) dense, one matrix stream for all k."""
     use, interp = _dispatch()
     if use:
-        tm, tw = _tiles_2d("ell_spmm", cols, vals.dtype, tm, tw)
+        tm, tw = _tiles_2d("ell_spmm", cols, vals.dtype, tm, tw, x.shape[1])
         return _ell_spmm_pallas(cols, vals, x, tm=tm, tw=tw, interpret=interp)
     return ref.ell_spmm_ref(cols, vals, x)
 
@@ -136,7 +175,8 @@ def ell_spmm_dot(cols, vals, x, tm: int | None = None, tw: int | None = None):
     """Multi-RHS fused SpMM + dot; x (n, k) -> (Y (n, k), pap (k,))."""
     use, interp = _dispatch()
     if use:
-        tm, tw = _tiles_2d("ell_spmm_dot", cols, vals.dtype, tm, tw)
+        tm, tw = _tiles_2d("ell_spmm_dot", cols, vals.dtype, tm, tw,
+                            x.shape[1])
         return _ell_spmm_dot_pallas(cols, vals, x, tm=tm, tw=tw, interpret=interp)
     return ref.ell_spmm_dot_ref(cols, vals, x)
 
@@ -158,7 +198,8 @@ def ell_spmm_pfold_dot(cols, vals, z, p, beta,
     """Multi-RHS p-fold (kernel layout (n, k), beta (k,))."""
     use, interp = _dispatch()
     if use:
-        tm, tw = _tiles_2d("ell_spmm_pfold_dot", cols, vals.dtype, tm, tw)
+        tm, tw = _tiles_2d("ell_spmm_pfold_dot", cols, vals.dtype, tm, tw,
+                            z.shape[1])
         return _ell_spmm_pfold_dot_pallas(cols, vals, z, p, beta,
                                           tm=tm, tw=tw, interpret=interp)
     return ref.ell_spmm_pfold_dot_ref(cols, vals, z, p, beta)
@@ -272,7 +313,8 @@ def axpy_dot(a, x, y, tn: int | None = None):
     if use:
         if tn is None:
             hit = autotune.lookup("axpy_dot", x.shape, x.dtype) or {}
-            tn = _fit(x.shape[0], hit.get("tn") or DEFAULT_TN, 8)
+            tn = _lane_tile(x.shape[0], hit.get("tn") or DEFAULT_TN, 6,
+                            x.dtype.itemsize)
         return _axpy_dot_pallas(a, x, y, tn=tn, interpret=interp)
     return ref.axpy_dot_ref(a, x, y)
 
@@ -284,6 +326,9 @@ def cg_update(alpha, x, r, p, ap, dinv=None, tn: int | None = None):
     if use:
         if tn is None:
             hit = autotune.lookup("cg_update", x.shape, r.dtype) or {}
-            tn = min(hit.get("tn") or DEFAULT_TN, x.shape[-1])
+            k = x.shape[0] if x.ndim == 2 else 1
+            # 5 reads + 3 writes per RHS, double-buffered
+            tn = _lane_tile(x.shape[-1], hit.get("tn") or DEFAULT_TN,
+                            16 * k, r.dtype.itemsize)
         return _cg_update_pallas(alpha, x, r, p, ap, dinv, tn=tn, interpret=interp)
     return ref.cg_update_ref(alpha, x, r, p, ap, dinv)

@@ -6,24 +6,24 @@ the dot is a second full HBM pass over ``p`` and ``ap`` right after the SpMV
 wrote them.  Azul's PE computes the reduction while the matrix block streams
 past; the TPU analogue is to emit per-row-tile dot partials from the SpMV
 kernel itself, on the last width step, when the accumulated ``y`` tile is
-complete and still VMEM-resident.  The wrapper sums the (rows_p / TM,)
-partials -- a deterministic, tiny reduction.
+complete and still VMEM-resident.  Each tile writes a lane-dense (k, 128)
+block of per-lane partial sums; the wrapper sums them -- a deterministic,
+tiny reduction.
 
 Requires a square padded operator (``x.shape[-1] == rows_p``) -- the layout
 the solvers run in (vectors padded to ``n_pad == rows_padded``), where the
 row tile of ``x`` aligns with the row tile of ``y``.
 
-Tiling matches ``ell_spmv``: grid = (rows_p / TM, width / TW), width
-innermost so the output row tile accumulates in VMEM; ``x`` is fully
-VMEM-resident for the gather (Azul's "x halo in SRAM").  The multi-RHS
-variant (``ell_spmm_dot``) amortizes the one matrix stream over k stacked
-vectors and emits per-RHS dot partials.
+Streaming and tiling match ``ell_spmv`` (lane-major (w, rows_p) planes,
+``x[cols]`` gathered by XLA ahead of the kernel, grid = (ceil(rows_p / TM),
+w / TW) with width innermost).  The multi-RHS variant (``ell_spmm_dot``)
+amortizes the one matrix stream over k stacked vectors and emits per-RHS
+dot partials.
 
-The ``*_pfold_dot`` variants additionally fold the CG search-direction
-update into the same stream: ``p = z + beta * p`` is computed once, on the
-first grid step, into the VMEM-resident output block that every subsequent
-gather reads -- the iteration's last standalone vector op (a 3n
-read-modify-write) disappears from HBM traffic entirely.
+The ``*_pfold_dot`` variants fold the CG search-direction update into the
+same call: ``p = z + beta * p`` is computed by XLA ahead of the gather that
+consumes it (one fused elementwise-plus-gather pass), so the iteration has
+no standalone p-update op.
 """
 
 from __future__ import annotations
@@ -34,33 +34,93 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["ell_spmv_dot", "ell_spmm_dot", "ell_spmv_pfold_dot", "ell_spmm_pfold_dot"]
+from .ell_spmv import _Z, DEFAULT_TM, DEFAULT_TW, lane_major, row_tiles
 
-DEFAULT_TM = 128
-DEFAULT_TW = 128
+__all__ = ["ell_spmv_dot", "ell_spmm_dot", "ell_spmv_pfold_dot",
+           "ell_spmm_pfold_dot", "stream_rows_dot"]
+
+LANES = 128
 
 
-def _spmv_dot_kernel(cols_ref, vals_ref, x_ref, xr_ref, y_ref, pap_ref):
+def lane_fold(v, width: int):
+    """Sum the (k, T) tile ``v`` down to (k, width) by adding its
+    lane-aligned column slabs (T is a multiple of ``width``): VPU adds
+    only, no cross-lane reduction inside the kernel."""
+    acc = v[:, :width]
+    for c in range(1, v.shape[1] // width):
+        acc = acc + v[:, c * width:(c + 1) * width]
+    return acc
+
+
+def masked_lane_sum(v, i, n: int, width: int):
+    """Per-lane partial sums of tile ``i`` (``v`` is (k, T)), dropping the
+    entries at or past the true length ``n`` -- the clipped tail of a
+    ragged last tile holds garbage."""
+    idx = i * v.shape[1] + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+    return lane_fold(jnp.where(idx < n, v, 0), width)
+
+
+def partial_width(tm: int) -> int:
+    """Lanes per tile of the partial-sum output: 128 when the tile is a
+    lane multiple, else the whole (single, full-dimension) tile."""
+    return LANES if tm % LANES == 0 else tm
+
+
+def _rows_dot_kernel(v_ref, g_ref, xr_ref, y_ref, pp_ref, *, rows_p: int):
+    i = pl.program_id(0)
     j = pl.program_id(1)
     nw = pl.num_programs(1)
-    c = cols_ref[...]          # (TM, TW) int32
-    v = vals_ref[...]          # (TM, TW) f32/f64
-    x = x_ref[...]             # (N,)     fully resident
-    partial = jnp.sum(v * x[c], axis=1)
+    part = jnp.sum(v_ref[...][None] * g_ref[...], axis=1)   # (k, TM)
 
     @pl.when(j == 0)
     def _init():
-        y_ref[...] = partial
+        y_ref[...] = part
 
     @pl.when(j != 0)
     def _acc():
-        y_ref[...] = y_ref[...] + partial
+        y_ref[...] = y_ref[...] + part
 
     @pl.when(j == nw - 1)
     def _dot():
         # y tile is complete and still in VMEM: fold the dot partial here,
-        # against the row-aligned tile of x -- no second pass over ap.
-        pap_ref[0] = jnp.sum(y_ref[...] * xr_ref[...])
+        # against the row-aligned tile of x -- no second pass over ap
+        pp_ref[...] = masked_lane_sum(y_ref[...] * xr_ref[...], i, rows_p,
+                                      pp_ref.shape[1])
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "tw", "interpret"))
+def stream_rows_dot(cols, vals, xs, tm: int = DEFAULT_TM,
+                    tw: int = DEFAULT_TW, interpret: bool = False):
+    """(Y, pap) with Y = (A @ xs^T)^T (k, rows_p) and pap[j] = dot(xs[j],
+    Y[j]) (k,), from one matrix stream.  ``xs`` is (k, rows_p)."""
+    rows_p, w = cols.shape
+    k = xs.shape[0]
+    if xs.shape[1] != rows_p:
+        raise ValueError(
+            f"fused SpMV + dot needs a square padded operator: x {xs.shape} "
+            f"vs rows {rows_p}")
+    tm, tw, grid = row_tiles(rows_p, w, tm, tw)
+    pw = partial_width(tm)
+    vt, xg = lane_major(cols, vals, xs)
+    y, partials = pl.pallas_call(
+        functools.partial(_rows_dot_kernel, rows_p=rows_p),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((tw, tm), lambda i, j: (j, i)),
+            pl.BlockSpec((k, tw, tm), lambda i, j: (_Z, j, i)),
+            pl.BlockSpec((k, tm), lambda i, j: (_Z, i)),
+        ],
+        out_specs=[
+            pl.BlockSpec((k, tm), lambda i, j: (_Z, i)),
+            pl.BlockSpec((k, pw), lambda i, j: (_Z, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((k, rows_p), vals.dtype),
+            jax.ShapeDtypeStruct((k, grid[0] * pw), vals.dtype),
+        ],
+        interpret=interpret,
+    )(vt, xg, xs)
+    return y, jnp.sum(partials, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "tw", "interpret"))
@@ -77,57 +137,9 @@ def ell_spmv_dot(
     A is padded ELL ((rows_p, W) cols/vals, padding vals == 0) and must be
     square in the padded layout: x.shape == (rows_p,).
     """
-    rows_p, w = cols.shape
-    if x.shape != (rows_p,):
-        raise ValueError(
-            f"ell_spmv_dot needs a square padded operator: x {x.shape} vs rows {rows_p}"
-        )
-    tm = min(tm, rows_p)
-    tw = min(tw, w)
-    if rows_p % tm or w % tw:
-        raise ValueError(f"ELL shape ({rows_p},{w}) not divisible by tile ({tm},{tw})")
-    grid = (rows_p // tm, w // tw)
-    y, partials = pl.pallas_call(
-        _spmv_dot_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tm, tw), lambda i, j: (i, j)),
-            pl.BlockSpec((tm, tw), lambda i, j: (i, j)),
-            pl.BlockSpec((x.shape[0],), lambda i, j: (0,)),
-            pl.BlockSpec((tm,), lambda i, j: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tm,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows_p,), vals.dtype),
-            jax.ShapeDtypeStruct((rows_p // tm,), vals.dtype),
-        ],
-        interpret=interpret,
-    )(cols, vals, x, x)
-    return y, jnp.sum(partials)
-
-
-def _spmm_dot_kernel(cols_ref, vals_ref, x_ref, xr_ref, y_ref, pap_ref):
-    j = pl.program_id(1)
-    nw = pl.num_programs(1)
-    c = cols_ref[...]          # (TM, TW) int32
-    v = vals_ref[...]          # (TM, TW)
-    x = x_ref[...]             # (N, K)   fully resident
-    partial = jnp.sum(v[..., None] * x[c], axis=1)   # (TM, K)
-
-    @pl.when(j == 0)
-    def _init():
-        y_ref[...] = partial
-
-    @pl.when(j != 0)
-    def _acc():
-        y_ref[...] = y_ref[...] + partial
-
-    @pl.when(j == nw - 1)
-    def _dot():
-        pap_ref[0, :] = jnp.sum(y_ref[...] * xr_ref[...], axis=0)   # (K,)
+    y, pap = stream_rows_dot(cols, vals, x[None], tm=tm, tw=tw,
+                             interpret=interpret)
+    return y[0], pap[0]
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "tw", "interpret"))
@@ -144,73 +156,9 @@ def ell_spmm_dot(
     Y[:, j]) -- k per-RHS CG denominators from the one matrix stream."""
     if x.ndim != 2:
         raise ValueError(f"ell_spmm_dot expects x of shape (n, k), got {x.shape}")
-    rows_p, w = cols.shape
-    k = x.shape[1]
-    if x.shape[0] != rows_p:
-        raise ValueError(
-            f"ell_spmm_dot needs a square padded operator: x {x.shape} vs rows {rows_p}"
-        )
-    tm = min(tm, rows_p)
-    tw = min(tw, w)
-    if rows_p % tm or w % tw:
-        raise ValueError(f"ELL shape ({rows_p},{w}) not divisible by tile ({tm},{tw})")
-    grid = (rows_p // tm, w // tw)
-    y, partials = pl.pallas_call(
-        _spmm_dot_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tm, tw), lambda i, j: (i, j)),
-            pl.BlockSpec((tm, tw), lambda i, j: (i, j)),
-            pl.BlockSpec((x.shape[0], k), lambda i, j: (0, 0)),
-            pl.BlockSpec((tm, k), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows_p, k), vals.dtype),
-            jax.ShapeDtypeStruct((rows_p // tm, k), vals.dtype),
-        ],
-        interpret=interpret,
-    )(cols, vals, x, x)
-    return y, jnp.sum(partials, axis=0)
-
-
-# ---------------------------------------------------------------------------
-# p-fold variants: p = z + beta * p computed AT GATHER TIME, inside the same
-# matrix stream that consumes it -- the separate 3n p-update op disappears
-# ---------------------------------------------------------------------------
-
-
-def _spmv_pfold_dot_kernel(beta_ref, z_ref, pold_ref, cols_ref, vals_ref,
-                           p_ref, y_ref, pap_ref):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    nw = pl.num_programs(1)
-    tm = y_ref.shape[0]
-
-    @pl.when((i == 0) & (j == 0))
-    def _fold():
-        # the whole p update happens once, on the first grid step, into the
-        # VMEM-resident output block every later gather reads from
-        p_ref[...] = z_ref[...] + beta_ref[0] * pold_ref[...]
-
-    p = p_ref[...]
-    partial = jnp.sum(vals_ref[...] * p[cols_ref[...]], axis=1)
-
-    @pl.when(j == 0)
-    def _init():
-        y_ref[...] = partial
-
-    @pl.when(j != 0)
-    def _acc():
-        y_ref[...] = y_ref[...] + partial
-
-    @pl.when(j == nw - 1)
-    def _dot():
-        pr = jax.lax.dynamic_slice(p, (i * tm,), (tm,))
-        pap_ref[0] = jnp.sum(y_ref[...] * pr)
+    y, pap = stream_rows_dot(cols, vals, x.T, tm=tm, tw=tw,
+                             interpret=interpret)
+    return y.T, pap
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "tw", "interpret"))
@@ -225,71 +173,18 @@ def ell_spmv_pfold_dot(
     interpret: bool = False,
 ):
     """Fused p-update + SpMV + dot: p' = z + beta*p, y = A @ p', pap =
-    dot(p', y) -- one matrix stream, no separate p-update pass.  Square
+    dot(p', y) -- one matrix stream, no separate p-update op.  Square
     padded operator as in ``ell_spmv_dot``; returns (p', y, pap)."""
-    rows_p, w = cols.shape
+    rows_p = cols.shape[0]
     if z.shape != (rows_p,) or p.shape != (rows_p,):
         raise ValueError(
             f"ell_spmv_pfold_dot needs square padded vectors: z {z.shape} / "
             f"p {p.shape} vs rows {rows_p}"
         )
-    tm = min(tm, rows_p)
-    tw = min(tw, w)
-    if rows_p % tm or w % tw:
-        raise ValueError(f"ELL shape ({rows_p},{w}) not divisible by tile ({tm},{tw})")
-    grid = (rows_p // tm, w // tw)
-    beta_arr = jnp.reshape(jnp.asarray(beta, vals.dtype), (1,))
-    p_new, y, partials = pl.pallas_call(
-        _spmv_pfold_dot_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-            pl.BlockSpec((rows_p,), lambda i, j: (0,)),
-            pl.BlockSpec((rows_p,), lambda i, j: (0,)),
-            pl.BlockSpec((tm, tw), lambda i, j: (i, j)),
-            pl.BlockSpec((tm, tw), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((rows_p,), lambda i, j: (0,)),
-            pl.BlockSpec((tm,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows_p,), vals.dtype),
-            jax.ShapeDtypeStruct((rows_p,), vals.dtype),
-            jax.ShapeDtypeStruct((rows_p // tm,), vals.dtype),
-        ],
-        interpret=interpret,
-    )(beta_arr, z, p, cols, vals)
-    return p_new, y, jnp.sum(partials)
-
-
-def _spmm_pfold_dot_kernel(beta_ref, z_ref, pold_ref, cols_ref, vals_ref,
-                           p_ref, y_ref, pap_ref):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    nw = pl.num_programs(1)
-    tm, k = y_ref.shape
-
-    @pl.when((i == 0) & (j == 0))
-    def _fold():
-        p_ref[...] = z_ref[...] + beta_ref[...] * pold_ref[...]   # (N, K)
-
-    p = p_ref[...]
-    partial = jnp.sum(vals_ref[...][..., None] * p[cols_ref[...]], axis=1)
-
-    @pl.when(j == 0)
-    def _init():
-        y_ref[...] = partial
-
-    @pl.when(j != 0)
-    def _acc():
-        y_ref[...] = y_ref[...] + partial
-
-    @pl.when(j == nw - 1)
-    def _dot():
-        pr = jax.lax.dynamic_slice(p, (i * tm, jnp.int32(0)), (tm, k))
-        pap_ref[0, :] = jnp.sum(y_ref[...] * pr, axis=0)
+    pn = z + jnp.asarray(beta, vals.dtype) * p
+    y, pap = stream_rows_dot(cols, vals, pn[None], tm=tm, tw=tw,
+                             interpret=interpret)
+    return pn, y[0], pap[0]
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "tw", "interpret"))
@@ -308,39 +203,13 @@ def ell_spmm_pfold_dot(
     pap[j] = dot(p'[:, j], Y[:, j]) -- one matrix stream for everything."""
     if z.ndim != 2:
         raise ValueError(f"ell_spmm_pfold_dot expects (n, k) vectors, got {z.shape}")
-    rows_p, w = cols.shape
-    k = z.shape[1]
+    rows_p = cols.shape[0]
     if z.shape[0] != rows_p or p.shape != z.shape:
         raise ValueError(
             f"ell_spmm_pfold_dot needs square padded vectors: z {z.shape} / "
             f"p {p.shape} vs rows {rows_p}"
         )
-    tm = min(tm, rows_p)
-    tw = min(tw, w)
-    if rows_p % tm or w % tw:
-        raise ValueError(f"ELL shape ({rows_p},{w}) not divisible by tile ({tm},{tw})")
-    grid = (rows_p // tm, w // tw)
-    beta_arr = jnp.broadcast_to(jnp.asarray(beta, vals.dtype).reshape(1, -1), (1, k))
-    full = lambda: pl.BlockSpec((rows_p, k), lambda i, j: (0, 0))
-    p_new, y, partials = pl.pallas_call(
-        _spmm_pfold_dot_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, k), lambda i, j: (0, 0)),
-            full(), full(),
-            pl.BlockSpec((tm, tw), lambda i, j: (i, j)),
-            pl.BlockSpec((tm, tw), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            full(),
-            pl.BlockSpec((tm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows_p, k), vals.dtype),
-            jax.ShapeDtypeStruct((rows_p, k), vals.dtype),
-            jax.ShapeDtypeStruct((rows_p // tm, k), vals.dtype),
-        ],
-        interpret=interpret,
-    )(beta_arr, z, p, cols, vals)
-    return p_new, y, jnp.sum(partials, axis=0)
+    pn = z + jnp.reshape(jnp.asarray(beta, vals.dtype), (1, -1)) * p
+    y, pap = stream_rows_dot(cols, vals, pn.T, tm=tm, tw=tw,
+                             interpret=interpret)
+    return pn, y.T, pap

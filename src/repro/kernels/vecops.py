@@ -14,15 +14,15 @@ need:
     rr = dot(r', r')          (residual norm for the trace)
     rz = dot(r', z)           (the next beta's numerator)
 
-Five vector reads, three writes, both dots emitted as per-tile partials in
-the same pass -- vs. five separate XLA ops re-streaming everything.  Tail
-tiles are masked (a VMEM iota against the true ``n``), so arbitrary vector
-lengths work: the wrapper zero-pads to the tile multiple and the mask keeps
-the dot partials exact even for non-divisible ``n``.  The batched variant
-takes ``(k, n)`` stacked vectors with per-RHS ``(k, 1)`` alphas and emits
-per-RHS dot partials, matching the solvers' multi-RHS layout.
+Five vector reads, three writes (two without ``dinv``: z is r'), both dots
+emitted as per-tile partials in the same pass -- vs. five separate XLA ops
+re-streaming everything.  Vectors run as (k, n) rows (a single vector is
+k = 1), tiled along n in lane-dense (k, TN) blocks; each tile writes its dot
+partials as a (k, 128) block of per-lane sums that the wrapper adds up.
+Arbitrary n: the last tile is clipped by the pipeline and masked (an iota
+against the true ``n``) so the dot partials stay exact.
 
-grid = (ceil(n / TN),); VMEM ~ (5 reads + 3 writes) * TN words + partials.
+grid = (ceil(n / TN),); VMEM ~ 2 * (5 reads + 3 writes) * k * TN words.
 """
 
 from __future__ import annotations
@@ -33,16 +33,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .ell_spmv import _Z
+from .spmv_dot import masked_lane_sum, partial_width
+
 __all__ = ["axpy_dot", "cg_update"]
 
-DEFAULT_TN = 1024
+DEFAULT_TN = 8192
 
 
-def _kernel(a_ref, x_ref, y_ref, z_ref, p_ref):
-    a = a_ref[0]
-    z = y_ref[...] + a * x_ref[...]
+def _axpy_dot_kernel(a_ref, x_ref, y_ref, z_ref, p_ref, *, n):
+    z = y_ref[...] + a_ref[...] * x_ref[...]
     z_ref[...] = z
-    p_ref[0] = jnp.sum(z * z)
+    p_ref[...] = masked_lane_sum(z * z, pl.program_id(0), n, p_ref.shape[1])
 
 
 @functools.partial(jax.jit, static_argnames=("tn", "interpret"))
@@ -56,29 +58,21 @@ def axpy_dot(
     """Returns (z, zz) with z = y + a*x and zz = dot(z, z)."""
     (n,) = x.shape
     tn = min(tn, n)
-    if n % tn:
-        raise ValueError(f"n {n} not divisible by tile {tn}")
-    grid = (n // tn,)
-    a_arr = jnp.reshape(a, (1,)).astype(x.dtype)
+    pw = partial_width(tn)
+    nt = pl.cdiv(n, tn)
+    vec = lambda: pl.BlockSpec((1, tn), lambda i: (_Z, i))
     z, partials = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((tn,), lambda i: (i,)),
-            pl.BlockSpec((tn,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tn,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
+        functools.partial(_axpy_dot_kernel, n=n),
+        grid=(nt,),
+        in_specs=[pl.BlockSpec((1, 1), lambda i: (_Z, _Z)), vec(), vec()],
+        out_specs=[vec(), pl.BlockSpec((1, pw), lambda i: (_Z, i))],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), x.dtype),
-            jax.ShapeDtypeStruct((n // tn,), x.dtype),
+            jax.ShapeDtypeStruct((1, n), x.dtype),
+            jax.ShapeDtypeStruct((1, nt * pw), x.dtype),
         ],
         interpret=interpret,
-    )(a_arr, x, y)
-    return z, jnp.sum(partials)
+    )(jnp.reshape(a, (1, 1)).astype(x.dtype), x[None], y[None])
+    return z[0], jnp.sum(partials)
 
 
 # ---------------------------------------------------------------------------
@@ -86,71 +80,24 @@ def axpy_dot(
 # ---------------------------------------------------------------------------
 
 
-def _cg_update_kernel(a_ref, nv_ref, x_ref, r_ref, p_ref, ap_ref, d_ref,
-                      xo_ref, ro_ref, zo_ref, pp_ref):
+def _cg_update_kernel(a_ref, x_ref, r_ref, p_ref, ap_ref, *rest, n, jacobi):
     i = pl.program_id(0)
-    a = a_ref[0]
+    a = a_ref[...]                       # (k, 1) per-RHS alphas
+    if jacobi:
+        d_ref, xo_ref, ro_ref, zo_ref, rr_ref, rz_ref = rest
+    else:
+        xo_ref, ro_ref, rr_ref = rest
     xo_ref[...] = x_ref[...] + a * p_ref[...]
-    ro = r_ref[...] - a * ap_ref[...]
-    z = ro * d_ref[...]
+    ro = r_ref[...] - a * ap_ref[...]    # (k, TN)
     ro_ref[...] = ro
-    zo_ref[...] = z
-    tn = x_ref.shape[0]
-    idx = i * tn + jax.lax.broadcasted_iota(jnp.int32, (tn,), 0)
-    rm = jnp.where(idx < nv_ref[0], ro, jnp.zeros_like(ro))  # mask tail tile
-    pp_ref[0, 0] = jnp.sum(rm * ro)
-    pp_ref[0, 1] = jnp.sum(rm * z)
-
-
-def _cg_update_kernel_nod(a_ref, nv_ref, x_ref, r_ref, p_ref, ap_ref,
-                          xo_ref, ro_ref, zo_ref, pp_ref):
-    # identity-psolve variant: no dinv stream (z == r', rz == rr) -- the
-    # IC(0) substrate and unpreconditioned CG run this, saving the 1n
-    # all-ones vector read the general kernel would pay
-    i = pl.program_id(0)
-    a = a_ref[0]
-    xo_ref[...] = x_ref[...] + a * p_ref[...]
-    ro = r_ref[...] - a * ap_ref[...]
-    ro_ref[...] = ro
-    zo_ref[...] = ro
-    tn = x_ref.shape[0]
-    idx = i * tn + jax.lax.broadcasted_iota(jnp.int32, (tn,), 0)
-    rm = jnp.where(idx < nv_ref[0], ro, jnp.zeros_like(ro))
-    rr = jnp.sum(rm * ro)
-    pp_ref[0, 0] = rr
-    pp_ref[0, 1] = rr
-
-
-def _cg_update_kernel_b(a_ref, nv_ref, x_ref, r_ref, p_ref, ap_ref, d_ref,
-                        xo_ref, ro_ref, zo_ref, pp_ref):
-    i = pl.program_id(0)
-    a = a_ref[...]                       # (K, 1) per-RHS alphas
-    xo_ref[...] = x_ref[...] + a * p_ref[...]
-    ro = r_ref[...] - a * ap_ref[...]    # (K, TN)
-    z = ro * d_ref[...]                  # (TN,) dinv broadcasts over K
-    ro_ref[...] = ro
-    zo_ref[...] = z
-    tn = x_ref.shape[1]
-    idx = i * tn + jax.lax.broadcasted_iota(jnp.int32, (tn,), 0)
-    rm = jnp.where(idx < nv_ref[0], ro, jnp.zeros_like(ro))
-    pp_ref[0, 0, :] = jnp.sum(rm * ro, axis=1)
-    pp_ref[0, 1, :] = jnp.sum(rm * z, axis=1)
-
-
-def _cg_update_kernel_b_nod(a_ref, nv_ref, x_ref, r_ref, p_ref, ap_ref,
-                            xo_ref, ro_ref, zo_ref, pp_ref):
-    i = pl.program_id(0)
-    a = a_ref[...]                       # (K, 1) per-RHS alphas
-    xo_ref[...] = x_ref[...] + a * p_ref[...]
-    ro = r_ref[...] - a * ap_ref[...]    # (K, TN)
-    ro_ref[...] = ro
-    zo_ref[...] = ro
-    tn = x_ref.shape[1]
-    idx = i * tn + jax.lax.broadcasted_iota(jnp.int32, (tn,), 0)
-    rm = jnp.where(idx < nv_ref[0], ro, jnp.zeros_like(ro))
-    rr = jnp.sum(rm * ro, axis=1)
-    pp_ref[0, 0, :] = rr
-    pp_ref[0, 1, :] = rr
+    width = rr_ref.shape[1]
+    rr_ref[...] = masked_lane_sum(ro * ro, i, n, width)
+    if jacobi:
+        # identity-psolve plans (IC(0), unpreconditioned CG) skip this
+        # branch: no dinv stream, no z write -- z is r'
+        z = ro * d_ref[...]              # (1, TN) dinv broadcasts over k
+        zo_ref[...] = z
+        rz_ref[...] = masked_lane_sum(ro * z, i, n, width)
 
 
 @functools.partial(jax.jit, static_argnames=("tn", "interpret"))
@@ -168,85 +115,46 @@ def cg_update(
 
     ``x``/``r``/``p``/``ap``: (n,) or batched (k, n); ``alpha``: scalar or
     (k, 1); ``dinv``: (n,) Jacobi inverse diagonal or None (identity
-    psolve -- z comes back equal to r', and a dedicated kernel variant
-    skips the dinv stream entirely instead of multiplying by ones).
+    psolve -- z comes back equal to r', and the kernel skips the dinv
+    stream and the z write entirely instead of multiplying by ones).
     Returns (x', r', z, rr, rz) with rr/rz following the solvers' dot
     convention: () scalars for (n,) vectors, (k, 1) for batches.
-    Arbitrary n: inputs are zero-padded to the tile multiple and tail
-    tiles are masked in-kernel.
     """
     n = x.shape[-1]
     batched = x.ndim == 2
     dt = r.dtype
-    identity = dinv is None
+    jacobi = dinv is not None
+    rows = lambda v: jnp.asarray(v, dt).reshape(-1, n)
+    x, r, p, ap = (rows(v) for v in (x, r, p, ap))
+    k = x.shape[0]
     tn = min(tn, n)
-    npad = -(-n // tn) * tn
-    pad = npad - n
-
-    def padv(v):
-        if pad == 0:
-            return v
-        cfg = [(0, 0)] * (v.ndim - 1) + [(0, pad)]
-        return jnp.pad(v, cfg)
-
-    x, r, p, ap = (padv(jnp.asarray(v, dt)) for v in (x, r, p, ap))
-    dvecs = () if identity else (padv(jnp.asarray(dinv, dt)),)
-    nv = jnp.full((1,), n, jnp.int32)
-    grid = (npad // tn,)
-
-    if batched:
-        k = x.shape[0]
-        a_arr = jnp.broadcast_to(jnp.asarray(alpha, dt), (k, 1))
-        vec = lambda: pl.BlockSpec((k, tn), lambda i: (0, i))
-        dspec = () if identity else (pl.BlockSpec((tn,), lambda i: (i,)),)
-        xo, ro, zo, pp = pl.pallas_call(
-            _cg_update_kernel_b_nod if identity else _cg_update_kernel_b,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((k, 1), lambda i: (0, 0)),
-                pl.BlockSpec((1,), lambda i: (0,)),
-                vec(), vec(), vec(), vec(),
-                *dspec,
-            ],
-            out_specs=[
-                vec(), vec(), vec(),
-                pl.BlockSpec((1, 2, k), lambda i: (i, 0, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((k, npad), dt),
-                jax.ShapeDtypeStruct((k, npad), dt),
-                jax.ShapeDtypeStruct((k, npad), dt),
-                jax.ShapeDtypeStruct((npad // tn, 2, k), dt),
-            ],
-            interpret=interpret,
-        )(a_arr, nv, x, r, p, ap, *dvecs)
-        sums = jnp.sum(pp, axis=0)                       # (2, k)
-        return (xo[:, :n], ro[:, :n], zo[:, :n],
-                sums[0][:, None], sums[1][:, None])
-
-    a_arr = jnp.reshape(jnp.asarray(alpha, dt), (1,))
-    vec = lambda: pl.BlockSpec((tn,), lambda i: (i,))
-    dspec = () if identity else (vec(),)
-    xo, ro, zo, pp = pl.pallas_call(
-        _cg_update_kernel_nod if identity else _cg_update_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            vec(), vec(), vec(), vec(),
-            *dspec,
-        ],
-        out_specs=[
-            vec(), vec(), vec(),
-            pl.BlockSpec((1, 2), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((npad,), dt),
-            jax.ShapeDtypeStruct((npad,), dt),
-            jax.ShapeDtypeStruct((npad,), dt),
-            jax.ShapeDtypeStruct((npad // tn, 2), dt),
-        ],
+    pw = partial_width(tn)
+    nt = pl.cdiv(n, tn)
+    a_arr = jnp.broadcast_to(jnp.reshape(jnp.asarray(alpha, dt), (-1, 1)), (k, 1))
+    vec = lambda: pl.BlockSpec((k, tn), lambda i: (_Z, i))
+    part = lambda: pl.BlockSpec((k, pw), lambda i: (_Z, i))
+    vshape = jax.ShapeDtypeStruct((k, n), dt)
+    pshape = jax.ShapeDtypeStruct((k, nt * pw), dt)
+    dspec = (pl.BlockSpec((1, tn), lambda i: (_Z, i)),) if jacobi else ()
+    outs = pl.pallas_call(
+        functools.partial(_cg_update_kernel, n=n, jacobi=jacobi),
+        grid=(nt,),
+        in_specs=[pl.BlockSpec((k, 1), lambda i: (_Z, _Z)),
+                  vec(), vec(), vec(), vec(), *dspec],
+        out_specs=([vec(), vec(), vec(), part(), part()] if jacobi
+                   else [vec(), vec(), part()]),
+        out_shape=([vshape] * 3 + [pshape] * 2 if jacobi
+                   else [vshape] * 2 + [pshape]),
         interpret=interpret,
-    )(a_arr, nv, x, r, p, ap, *dvecs)
-    sums = jnp.sum(pp, axis=0)
-    return xo[:n], ro[:n], zo[:n], sums[0], sums[1]
+    )(a_arr, x, r, p, ap, *((rows(dinv),) if jacobi else ()))
+    if jacobi:
+        xo, ro, zo, rr, rz = outs
+        rr = jnp.sum(rr, axis=1, keepdims=True)
+        rz = jnp.sum(rz, axis=1, keepdims=True)
+    else:
+        xo, ro, rr = outs
+        zo = ro
+        rr = rz = jnp.sum(rr, axis=1, keepdims=True)
+    if batched:
+        return xo, ro, zo, rr, rz
+    return xo[0], ro[0], zo[0], rr[0, 0], rz[0, 0]

@@ -30,10 +30,8 @@ def make_mesh(shape, axes):
             f"mesh {shape} needs {n} devices, have {len(devs)} "
             f"(dry-runs must set XLA_FLAGS=--xla_force_host_platform_device_count=...)"
         )
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):  # absent on older jax releases
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, devices=devs[:n], **kw)
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

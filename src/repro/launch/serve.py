@@ -177,6 +177,8 @@ def main(argv=None):
                          "/trace.json) on this port for the run; 0 picks "
                          "an ephemeral port (printed at startup)")
     args = ap.parse_args(argv)
+    from .compile_cache import enable as enable_compile_cache
+    enable_compile_cache()
 
     if args.solver:
         return _solver_main(args)

@@ -3,20 +3,30 @@
     PYTHONPATH=src python -m repro.launch.solve --matrix lap2d_32 \
         --method pcg --precond block_ic0 --iters 100
 
-    # the headline tolerance-mode config -- IC(0) PCG solved to 1e-8,
-    # running the fused substrate by default:
+    # the headline tolerance-mode config -- IC(0) PCG solved to 1e-8 (an
+    # f64 target: pass --dtype float64):
     PYTHONPATH=src python -m repro.launch.solve --matrix lap2d_32 \
-        --method pcg_tol --precond block_ic0 --tol 1e-8
+        --method pcg_tol --precond block_ic0 --tol 1e-8 --dtype float64
 
 Add --mesh-shape 2x2 (any grid whose product <= device count) to run the
 distributed AzulEngine; on the CPU container use
 XLA_FLAGS=--xla_force_host_platform_device_count=N.
 
+Matrices: the suite names (``data.matrices.suite``), generated Laplacians
+of any size (``lap2d_<N>``: n = N**2, ``lap3d_<N>``: n = N**3), and
+matrix-free ``stencil:`` operators.  ``--dtype`` picks the engine precision
+(float32 by default -- the TPU kernels run f32; float64 turns on x64 and is
+for CPU verification runs):
+
+    # a million-row stored operator, f32, solved to 1e-5:
+    PYTHONPATH=src python -m repro.launch.solve --matrix lap2d_1024 \
+        --method pcg_tol --precond jacobi --tol 1e-5 --max-iters 6000
+
 Storage formats and matrix-free operators:
 
     # per-matrix format autotuner (skewed rows -> HYB beats padded ELL):
     PYTHONPATH=src python -m repro.launch.solve --matrix skew_1k \
-        --method pcg_tol --tol 1e-8 --format auto
+        --method pcg_tol --tol 1e-8 --format auto --dtype float64
 
     # million-row matrix-free solve -- no assembled CSR is ever built:
     PYTHONPATH=src python -m repro.launch.solve --matrix stencil:lap2d_1024 \
@@ -28,11 +38,14 @@ Fault-tolerance demo flags:
     # chunked restart driver detect it, roll back, and reconverge:
     PYTHONPATH=src python -m repro.launch.solve --matrix lap2d_32 \
         --method pcg_tol --max-iters 400 --inject nan --inject-at 15 \
-        --ft-chunk 20
+        --ft-chunk 20 --dtype float64
 
 --no-guard runs the lean pre-guard loop (the A/B baseline); every run
 reports the structured solve ``status`` (converged | maxiter | breakdown |
-diverged | stagnated | unguarded) in the JSON output.
+diverged | stagnated | unguarded) in the JSON output, with the true relative
+residual ``||b - A x|| / ||b||`` computed on the host in float64, the
+plan's compile and solve seconds (host clock), and the number of Pallas
+TPU custom calls in the compiled program.
 """
 
 from __future__ import annotations
@@ -45,7 +58,12 @@ import numpy as np
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--matrix", default="lap2d_32")
+    ap.add_argument("--matrix", default="lap2d_32",
+                    help="suite name, lap2d_<N>, lap3d_<N>, or "
+                         "stencil:lap2d_<N> / stencil:lap3d_<N>")
+    ap.add_argument("--dtype", default="float32",
+                    choices=("float32", "float64"),
+                    help="engine precision (float64 enables x64)")
     ap.add_argument("--method", default="pcg",
                     choices=("pcg", "pcg_tol", "pcg_pipelined",
                              "pcg_pipelined_tol", "pcg_pipe", "cg",
@@ -94,29 +112,35 @@ def main(argv=None):
                     help="persist solver state every chunk; reruns resume")
     args = ap.parse_args(argv)
 
-    # the engine below is built at dtype=float64: enable x64 so standalone
-    # CLI runs actually compute at the declared precision (without this,
-    # jax silently downcasts and a --tol 1e-8 solve floors out at the f32
-    # rounding level, reporting maxiter/stagnated instead of converged)
     import jax
-    jax.config.update("jax_enable_x64", True)
+    if args.dtype == "float64":
+        # without x64 jax silently downcasts, and a --tol 1e-8 solve floors
+        # out at the f32 rounding level (maxiter/stagnated, not converged)
+        jax.config.update("jax_enable_x64", True)
+    from .compile_cache import enable as enable_compile_cache
+    enable_compile_cache()
 
     from ..core.engine import AzulEngine
     from ..core.plan import SolveSpec
-    from ..data.matrices import suite
+    from ..obs import clock
 
     if args.matrix.startswith("stencil:"):
         # matrix-free operator, e.g. stencil:lap2d_1024 or stencil:lap3d_64
         # -- no assembled CSR, O(n) memory, so n can reach millions
-        from ..core.stencil import lap2d_stencil, lap3d_stencil
+        from ..core.stencil import (lap2d_stencil, lap3d_stencil,
+                                    stencil_matvec_host)
         kind, _, size = args.matrix[len("stencil:"):].partition("_")
         builder = {"lap2d": lap2d_stencil, "lap3d": lap3d_stencil}[kind]
         m = builder(int(size))
+        nnz = m.nnz_equiv
+        host_matvec = lambda v: stencil_matvec_host(m, v)
     else:
-        mats = suite("small")
-        if args.matrix not in mats:
-            mats.update(suite("large"))
-        m = mats[args.matrix]
+        import scipy.sparse as sp
+        from ..data.matrices import named
+        m = named(args.matrix)
+        nnz = m.nnz
+        a = sp.csr_matrix((m.data, m.indices, m.indptr), shape=m.shape)
+        host_matvec = lambda v: a @ v
 
     mesh = None
     if args.mesh_shape:
@@ -126,20 +150,12 @@ def main(argv=None):
 
     rng = np.random.default_rng(0)
     x_true = rng.standard_normal(m.shape[0])
-    from ..core.formats import csr_to_dense  # noqa -- only for tiny oracles
+    b = host_matvec(x_true)             # float64 on the host
     fused = {"auto": "auto", "on": True, "off": False}[args.fused]
     eng = AzulEngine(m, mesh=mesh, mode=args.mode, precond=args.precond,
-                     balance=args.balance, dtype=np.float64, fused=fused,
-                     layout=args.layout, reorder=args.reorder,
+                     balance=args.balance, dtype=np.dtype(args.dtype),
+                     fused=fused, layout=args.layout, reorder=args.reorder,
                      format=args.fmt)
-    if hasattr(m, "indptr"):
-        import scipy.sparse as sp
-        a = sp.csr_matrix((m.data, m.indices, m.indptr), shape=m.shape)
-        b = a @ x_true
-        nnz = m.nnz
-    else:
-        b = np.asarray(eng.spmv(x_true))   # matrix-free operators have no CSR
-        nnz = m.nnz_equiv
 
     spec = SolveSpec(method=args.method, iters=args.iters,
                      tol=args.tol, max_iters=args.max_iters,
@@ -174,12 +190,20 @@ def main(argv=None):
         print(json.dumps(out, indent=1))
         return 0 if rep.status == "converged" else 1
 
-    # plan/execute: lower the spec once, run the compiled plan
+    # plan/execute: lower and compile the spec once, run the compiled plan
     plan = eng.plan(spec)
-    x, norms = plan(b)
+    t0 = clock.now()
+    compiled = plan.compile()
+    compile_s = clock.now() - t0
+    t0 = clock.now()
+    x, norms = plan(b)                   # returns host arrays: synchronous
+    solve_s = clock.now() - t0
     rel = float(np.linalg.norm(x - x_true) / np.linalg.norm(x_true))
+    true_rel = float(np.linalg.norm(b - host_matvec(np.asarray(x, np.float64)))
+                     / np.linalg.norm(b))
     out = {
         "matrix": args.matrix, "n": m.shape[0], "nnz": nnz,
+        "dtype": args.dtype,
         "method": args.method, "precond": args.precond,
         "iters": args.iters, "mode": eng.mode,
         "substrate": plan.info["substrate"],
@@ -188,9 +212,16 @@ def main(argv=None):
         "layout": plan.info["layout"],
         "reorder": plan.info["reorder"],
         "final_residual": float(norms[-1] if norms.ndim == 1 else norms[-1, 0]),
+        "true_rel_residual": true_rel,
         "rel_error": rel,
         "status": plan.last_status_names,
         "bad_iter": int(np.asarray(plan.last_bad_iter)),
+        "compile_s": compile_s,
+        "solve_s": solve_s,
+        "tpu_custom_calls": compiled.as_text().count("tpu_custom_call"),
+        "device": {"platform": jax.devices()[0].platform,
+                   "kind": jax.devices()[0].device_kind,
+                   "count": len(jax.devices())},
     }
     if "noc" in plan.info:
         out["noc"] = plan.info["noc"]

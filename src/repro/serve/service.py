@@ -430,6 +430,13 @@ class SolveService:
         """Registry snapshot: {name: OperatorInfo}."""
         return {name: self._info(op) for name, op in self._operators.items()}
 
+    def retraces(self) -> int:
+        """Retraces across every built plan of every operator: 0 in the
+        compile-free steady state, where each plan traces exactly once."""
+        return sum(max(0, plan.traces - 1)
+                   for op in self._operators.values()
+                   for pool in op.pools.values() for plan in pool.values())
+
     def resident_bytes(self) -> int:
         return sum(op.bytes for op in self._operators.values()
                    if op.resident)
@@ -683,10 +690,15 @@ class SolveService:
 
     def _run_degradable(self, op: _Operator, plan, k_pad: int, batch,
                         x0=None, ref_flavor: str = "ref"):
-        """Execute ``plan``; on a fused-path failure (raise, or guards
-        reporting breakdown on any lane) retry ONCE on the reference
-        substrate.  Returns (x, norms, plan_used)."""
+        """Execute ``plan``; on a fused-path runtime failure (raise, or
+        guards reporting breakdown on any lane) retry ONCE on the reference
+        substrate.  Returns (x, norms, plan_used).
+
+        The plan is compiled first, outside the retry: a lowering or
+        compile failure is a broken program, not a runtime fault, and
+        propagates instead of becoming a degraded batch."""
         fused = bool(plan.info.get("fused"))
+        plan.compile()
         try:
             x, norms = plan(batch) if x0 is None else plan(batch, x0=x0)
             bad = any(s in _FAULT_RETRY
@@ -697,7 +709,7 @@ class SolveService:
             if not fused:
                 raise
         # one retry on the reference substrate: if the failure was the
-        # fused kernels' (a compile/runtime bug, a kernel-only numerical
+        # fused kernels' (a runtime fault, a kernel-only numerical
         # breakdown), the reference path answers; if the INPUT is bad the
         # reference guards re-report it and that status stands
         self.stats["degraded_batches"] += 1

@@ -320,6 +320,9 @@ class _ExplodingPlan:
     def __init__(self):
         self.calls = 0
 
+    def compile(self):
+        return None                     # compiled fine; fails when run
+
     def __call__(self, batch, x0=None, vals=None):
         self.calls += 1
         raise RuntimeError("fused kernel fault")

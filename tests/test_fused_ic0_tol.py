@@ -250,6 +250,24 @@ def test_substrate_selection_per_backend():
             assert e2.substrate_kind(method) != "reference", (method, pc)
 
 
+def test_ic0_auto_resolves_reference_where_kernel_cannot_compile(monkeypatch):
+    """On a TPU the ops dispatch to compiled kernels, and the whole-solve
+    SpTRSV kernel behind ``fused_ic0`` does not compile there: 'auto'
+    must resolve block_ic0 to the reference apply (and say so in the plan
+    info), while the Jacobi plans stay fused."""
+    monkeypatch.setattr(ops, "_dispatch", lambda: (True, False))
+    assert ops.kernels_active()
+    assert not ops.kernel_usable("sptrsv_solve_dot")
+    assert ops.kernel_usable("cg_update")
+    m = laplacian_2d(8)
+    eng = AzulEngine(m, precond="block_ic0", dtype=np.float32)
+    assert eng.substrate_kind("pcg_tol") == "reference"
+    assert eng.plan(method="pcg_tol", tol=1e-5).info["substrate"] == "reference"
+    assert eng.substrate_kind("pcg_tol", fused=True) == "fused_ic0"
+    assert AzulEngine(m, precond="jacobi",
+                      dtype=np.float32).substrate_kind("pcg_tol") == "fused"
+
+
 @pytest.mark.slow
 def test_launch_solve_cli_reports_fused_substrate(capsys):
     """The driver itself, end to end, reports the forced-fused substrate
@@ -261,7 +279,7 @@ def test_launch_solve_cli_reports_fused_substrate(capsys):
     launch_solve.main([
         "--matrix", "lap2d_32", "--method", "pcg_tol",
         "--precond", "block_ic0", "--tol", "1e-6", "--iters", "120",
-        "--fused", "on",
+        "--fused", "on", "--dtype", "float64",
     ])
     out = _json.loads(capsys.readouterr().out)
     assert out["substrate"] == "fused_ic0"

@@ -288,6 +288,9 @@ class _BoomPlan:
     def __init__(self):
         self.calls = 0
 
+    def compile(self):
+        return None                     # compiled fine; fails when run
+
     def __call__(self, batch, x0=None):
         self.calls += 1
         raise RuntimeError("injected fused-kernel failure")
@@ -305,6 +308,22 @@ def test_degrades_to_reference_chunks_on_fused_failure():
     a = _csr(m)
     b = _rhs(m.shape[0], 11)
     assert np.linalg.norm(b - a @ out.x) <= TOL * np.linalg.norm(b) * 10
+
+
+def test_compile_failure_propagates_not_degraded(monkeypatch):
+    """A fused plan whose program cannot be lowered or compiled is a broken
+    program, not a runtime fault: the error reaches the caller and no
+    batch is counted as degraded (a reference retry would hide it)."""
+    from repro.kernels import ops
+
+    svc, m = _service(8, chunk=8)
+    # compiled (non-interpret) Pallas kernels cannot lower on the CPU
+    # backend: the fused chunk plan fails while it is being built
+    monkeypatch.setattr(ops, "_dispatch", lambda: (True, False))
+    svc.submit(_rhs(m.shape[0], 5))
+    with pytest.raises(Exception, match="(?i)interpret"):
+        svc.drain()
+    assert svc.stats["degraded_batches"] == 0
 
 
 def test_fixed_iteration_method_serves_in_chunks():

@@ -87,10 +87,11 @@ def test_spmv_hyb_matches_scipy(n, density, seed):
 @settings(max_examples=10, deadline=None)
 def test_sptrsv_scan_bitwise_matches_unrolled(n, density, seed):
     """The lax.scan wavefront (O(1) traced statements, sublinear compile in
-    levels) must be BITWISE identical to the unrolled per-level Python loop
-    it replaced -- same arithmetic, different program shape.  Both sides
-    jit-compiled: eager dispatch fuses the level body differently and can
-    drift an ulp."""
+    levels) must match the unrolled per-level Python loop it replaced to
+    within a few ulps -- same arithmetic, different program shape.  XLA
+    does not promise bitwise equality across program shapes (it may fuse
+    or contract the level body differently), so the bound is 64 float64
+    ulps relative to the solution's magnitude."""
     import jax
 
     a = sp.random(n, n, density=density, random_state=seed, format="csr")
@@ -103,7 +104,9 @@ def test_sptrsv_scan_bitwise_matches_unrolled(n, density, seed):
         lambda bb: sptrsv_ell(e, sched, bb))(jnp.asarray(b)))
     x_unrl = np.asarray(jax.jit(
         lambda bb: sptrsv_ell_unrolled(e, sched, bb))(jnp.asarray(b)))
-    np.testing.assert_array_equal(x_scan, x_unrl)
+    ulp = np.finfo(np.float64).eps
+    np.testing.assert_allclose(x_scan, x_unrl, rtol=64 * ulp,
+                               atol=64 * ulp * np.abs(x_unrl).max())
 
 
 def test_extract_diag():
