@@ -87,6 +87,7 @@ from .levels import build_schedule
 from .partition import (padded_layout_1d, permute_csr, plan_1d, plan_2d,
                         rcm_permutation, tile_csr)
 from ..obs import REGISTRY as _OBS
+from ..obs.scopes import scope
 from .plan import PlanCache, SolvePlan, SolveSpec, canonicalize, warn_deprecated
 from .precond import ic0 as host_ic0
 from .solvers import ensure_status
@@ -633,29 +634,33 @@ class AzulEngine:
         if mode == "2d":
             def mv(x_loc, cols_loc, vals_loc):
                 va = x_loc.ndim - 1
-                xc = noc.mesh_transpose(x_loc, row_axes, col_axes)
-                if layout == "halo":
-                    xj = _pull(xc, row_axes, va)          # (..., (1+H)u)
-                else:
-                    xj = noc.gather_along(xc, row_axes, vec_axis=va)  # (..., bc)
+                with scope("halo"):
+                    xc = noc.mesh_transpose(x_loc, row_axes, col_axes)
+                    if layout == "halo":
+                        xj = _pull(xc, row_axes, va)      # (..., (1+H)u)
+                    else:
+                        xj = noc.gather_along(xc, row_axes, vec_axis=va)
                 yp = _local(cols_loc, vals_loc, xj)               # (..., br)
-                return noc.reduce_scatter_along(yp, col_axis, vec_axis=va)
+                with scope("halo"):
+                    return noc.reduce_scatter_along(yp, col_axis, vec_axis=va)
             return mv
 
         all_axes = self._all_axes
 
         def mv1d(x_loc, cols_loc, vals_loc):
             va = x_loc.ndim - 1
-            if layout == "halo":
-                xg = _pull(x_loc, all_axes, va)          # (..., (1+H)u)
-            else:
-                xg = noc.gather_along(x_loc, all_axes, vec_axis=va)  # (..., n_pad)
+            with scope("halo"):
+                if layout == "halo":
+                    xg = _pull(x_loc, all_axes, va)      # (..., (1+H)u)
+                else:
+                    xg = noc.gather_along(x_loc, all_axes, vec_axis=va)
             return _local(cols_loc, vals_loc, xg)                # (..., u)
         return mv1d
 
     def _dot(self):
         axes = self._all_axes
 
+        @scope("reduce")
         def dot(u, v):
             # last-axis reduce (keepdims when batched) + psum: per-RHS
             # scalars arrive as (k, 1), broadcastable back onto (k, u).
@@ -669,6 +674,7 @@ class AzulEngine:
         iteration reduction load ([gamma, delta, rr]) on a single call."""
         axes = self._all_axes
 
+        @scope("reduce")
         def dot2(*vs):
             kd = vs[0].ndim > 1
             return lax.psum(
@@ -703,6 +709,7 @@ class AzulEngine:
         deltas = self.comm_plan.deltas
         pull_axes = row_axes if mode == "2d" else self._all_axes
 
+        @scope("halo")
         def start(x_loc):
             xc = (noc.mesh_transpose(x_loc, row_axes, col_axes)
                   if mode == "2d" else x_loc)
@@ -710,16 +717,19 @@ class AzulEngine:
                 noc.pull_shard(xc, pull_axes, d) for d in deltas
             )
 
+        @scope("matvec")
         def finish(halo, cols_loc, vi_loc, vf_loc):
             xc, pulled = halo[0], halo[1:]
             va = xc.ndim - 1
-            x_int = jnp.concatenate(
-                [xc] + [jnp.zeros_like(s) for s in pulled], axis=va)
-            x_ext = jnp.concatenate([xc, *pulled], axis=va)
+            with scope("halo"):
+                x_int = jnp.concatenate(
+                    [xc] + [jnp.zeros_like(s) for s in pulled], axis=va)
+                x_ext = jnp.concatenate([xc, *pulled], axis=va)
             y = (_ell_block_apply(cols_loc, vi_loc, x_int)
                  + _ell_block_apply(cols_loc, vf_loc, x_ext))
             if mode == "2d":
-                return noc.reduce_scatter_along(y, col_axis, vec_axis=va)
+                with scope("halo"):
+                    return noc.reduce_scatter_along(y, col_axis, vec_axis=va)
             return y
 
         return start, finish

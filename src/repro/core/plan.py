@@ -35,13 +35,14 @@ import warnings
 from dataclasses import dataclass, replace
 from typing import Any, Callable
 
+import jax
 import numpy as np
 
 from . import registry
 from ..obs import REGISTRY as _OBS
 from ..obs import clock as _clock
 from ..obs import span as _span
-from ..obs.metrics import enabled as _obs_enabled
+from ..obs.scopes import SCOPES as _SCOPES
 
 __all__ = ["SolveSpec", "SolvePlan", "PlanCache", "chunk_spec"]
 
@@ -59,11 +60,23 @@ _M_EXECUTIONS = _OBS.counter(
     "repro_solve_executions_total", "SolvePlan executions", ("method",))
 _M_COMPILE_S = _OBS.histogram(
     "repro_plan_compile_seconds",
-    "wall time of executions that (re)traced: trace + compile + run",
+    "SolvePlan.compile() wall time: trace + lower + compile (or cache load)",
     ("method",))
 _M_SOLVE_S = _OBS.histogram(
     "repro_solve_seconds",
-    "steady-state execution wall time (block_until_ready)", ("method",))
+    "execution wall time: dispatch to outputs ready (block_until_ready)",
+    ("method",))
+_M_STAGE_S = _OBS.histogram(
+    "repro_solve_stage_seconds",
+    "host staging wall time per solve: 'in' builds and transfers the "
+    "device operands, 'out' copies the outputs back and un-pads x",
+    ("phase",))
+_M_H2D = _OBS.counter(
+    "repro_solve_h2d_bytes_total",
+    "bytes of the arrays SolvePlan calls hand to the device")
+_M_D2H = _OBS.counter(
+    "repro_solve_d2h_bytes_total",
+    "bytes of the arrays SolvePlan calls take back from the device")
 
 
 @dataclass(frozen=True)
@@ -331,7 +344,9 @@ class SolvePlan:
             )
 
     def _operands(self, b, x0, vals):
-        """The program's device operands for host (b, x0[, vals])."""
+        """The program's device operands for host (b, x0[, vals]), and
+        the bytes handed to the device for them (a ``vals=None`` operand
+        is the engine's resident buffer: no transfer)."""
         eng = self.engine
         args = (eng.to_device_vec(b), eng.to_device_vec(x0))
         if self.spec.injectable:
@@ -340,7 +355,8 @@ class SolvePlan:
             raise ValueError(
                 "this plan closes over the matrix values as constants; "
                 "build the spec with injectable=True to pass vals per call")
-        return args
+        sent = args if vals is not None else args[:2]
+        return args, sum(a.nbytes for a in sent)
 
     def compile(self):
         """Lower and compile the program once; returns the compiled
@@ -348,13 +364,27 @@ class SolvePlan:
         execution runs this executable, so a lowering or compile failure
         surfaces here, before anything executes -- callers that retry
         runtime faults (the serving layer) compile first and let such
-        failures propagate."""
+        failures propagate.
+
+        The first call runs under a ``plan_compile`` span, feeds
+        ``repro_plan_compile_seconds``, and registers the executable with
+        ``repro.obs.scopes.SCOPES`` (parsed only when read)."""
         if self._exe is None:
             shape = ((self.engine.n,) if self.spec.batch is None
                      else (self.spec.batch, self.engine.n))
             zeros = np.zeros(shape)
-            self._exe = self._fn.lower(
-                *self._operands(zeros, zeros, None)).compile()
+            args, _ = self._operands(zeros, zeros, None)
+            method = self.spec.method
+            tr0 = self._trace_cell[0]
+            t0 = _clock.now()
+            with _span("plan_compile", kind="plan_compile", method=method):
+                exe = self._fn.lower(*args).compile()
+            _M_COMPILE_S.observe(_clock.now() - t0, method=method)
+            retraces = self._trace_cell[0] - tr0 - (1 if tr0 == 0 else 0)
+            if retraces > 0:
+                _M_RETRACES.inc(retraces)
+            _SCOPES.register(self, exe)
+            self._exe = exe
         return self._exe
 
     def __call__(self, b, x0=None, vals=None):
@@ -365,55 +395,61 @@ class SolvePlan:
 
         ``vals`` (injectable plans only) substitutes the matrix value
         buffer for THIS call -- same shape/dtype as the engine's packed
-        values; None runs the clean operator."""
+        values; None runs the clean operator.
+
+        The call is a ``solve`` span with three children -- ``stage_in``
+        (initial guess, padding, host-to-device transfer), ``execute``
+        (dispatch to outputs ready) and ``stage_out`` (outputs back to the
+        host, x un-padded) -- and feeds ``repro_solve_stage_seconds``,
+        ``repro_solve_seconds`` and the transfer byte counters.  All of it
+        is host-side: the program is untouched, so instrumented solves are
+        bitwise identical to bare ones (asserted in tests/test_obs.py)."""
         b = np.asarray(b)
         self._check(b)
-        if x0 is None:
-            x0 = np.zeros(b.shape)
-        else:
-            x0 = np.asarray(x0)
-            if b.ndim == 2 and x0.ndim == 1:
-                # a shared (n,) initial guess for a (k, n) batch: broadcast
-                # so b and x0 agree on the batched sharding spec
-                x0 = np.broadcast_to(x0, b.shape)
+        exe = self.compile()
+        method = self.spec.method
         eng = self.engine
-        args = self._operands(b, x0, vals)
-        if _obs_enabled():
-            # host-side timing only: block_until_ready on the outputs we
-            # were about to convert to numpy anyway -- the traced program
-            # is untouched, so instrumented solves stay bitwise identical
-            # to bare ones (asserted in tests/test_obs.py)
-            import jax
-
-            tr0 = self._trace_cell[0]
+        with _span("solve", kind="solve", method=method):
             t0 = _clock.now()
-            with _span("solve", kind="solve", method=self.spec.method):
-                out = self.compile()(*args)
+            with _span("solve.stage_in"):
+                if x0 is None:
+                    x0 = np.zeros(b.shape)
+                else:
+                    x0 = np.asarray(x0)
+                    if b.ndim == 2 and x0.ndim == 1:
+                        # a shared (n,) initial guess for a (k, n) batch:
+                        # broadcast so b and x0 agree on the batched
+                        # sharding spec
+                        x0 = np.broadcast_to(x0, b.shape)
+                args, h2d = self._operands(b, x0, vals)
+            t1 = _clock.now()
+            with _span("solve.execute"):
+                out = exe(*args)
                 jax.block_until_ready(out)
-            dt = _clock.now() - t0
-            traced = self._trace_cell[0] - tr0
-            _M_EXECUTIONS.inc(method=self.spec.method)
-            if traced:
-                _M_COMPILE_S.observe(dt, method=self.spec.method)
-                retraces = traced - (1 if tr0 == 0 else 0)
-                if retraces > 0:
-                    _M_RETRACES.inc(retraces)
-            else:
-                _M_SOLVE_S.observe(dt, method=self.spec.method)
-        else:
-            out = self.compile()(*args)
-        x, norms, its, status, bad = out
+            t2 = _clock.now()
+            with _span("solve.stage_out"):
+                d2h = sum(a.nbytes for a in out)
+                x, norms, its, status, bad = out
+                x = eng.from_device_vec(np.asarray(x))
+                norms = np.asarray(norms)
+                self.last_iters = np.asarray(its)
+                self.last_status = np.asarray(status)
+                self.last_bad_iter = np.asarray(bad)
+            t3 = _clock.now()
+        _M_EXECUTIONS.inc(method=method)
+        _M_SOLVE_S.observe(t2 - t1, method=method)
+        _M_STAGE_S.observe(t1 - t0, phase="in")
+        _M_STAGE_S.observe(t3 - t2, phase="out")
+        _M_H2D.inc(h2d)
+        _M_D2H.inc(d2h)
         self.executions += 1
-        self.last_iters = np.asarray(its)
-        self.last_status = np.asarray(status)
-        self.last_bad_iter = np.asarray(bad)
         info = dict(self.info)
         info["iters"] = self.last_iters
         info["status"] = self.last_status
         info["status_names"] = self.last_status_names
         info["bad_iter"] = self.last_bad_iter
         eng.last_solve_info = info
-        return eng.from_device_vec(np.asarray(x)), np.asarray(norms)
+        return x, norms
 
     def hlo_summary(self, refresh: bool = False) -> dict:
         """Collective-instruction summary of this plan's lowered program

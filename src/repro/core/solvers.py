@@ -47,6 +47,11 @@ recurrence already reduced.
 
 Convergence bookkeeping (residual-norm trace) is carried through the scan so
 benchmarks can plot paper-style convergence curves without re-running.
+
+Each solver runs under the ``control`` scope (``repro.obs.scopes``): the
+loop, the stopping test, the guards, the freeze selects and the residual
+ring are ``control``; the operator, preconditioner, update and reduction
+ops inside carry their own, inner scopes.
 """
 
 from __future__ import annotations
@@ -56,6 +61,7 @@ from typing import Callable, NamedTuple
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.scopes import scope
 from .substrate import SolverSubstrate, reference_substrate
 from .substrate import pipe_update as _pipe_update
 
@@ -128,6 +134,7 @@ class SolveResult(NamedTuple):
     bad_iter: jnp.ndarray | None = None
 
 
+@scope("reduce")
 def _default_dot(u: Vec, v: Vec) -> jnp.ndarray:
     """Last-axis dot: () for (n,) vectors, (k, 1) for (k, n) batches --
     broadcastable back against the vectors it was computed from."""
@@ -196,6 +203,7 @@ def ensure_status(res: SolveResult, b: Vec) -> SolveResult:
     return SolveResult(res.x, res.res_norms, res.iters, status, bad)
 
 
+@scope("control")
 def cg(
     matvec: MatVec,
     b: Vec,
@@ -210,6 +218,7 @@ def cg(
                substrate=substrate, guard=guard)
 
 
+@scope("control")
 def pcg(
     matvec: MatVec,
     b: Vec,
@@ -368,6 +377,7 @@ def _pipe_guard(gd, rn, rn_prev, r0n):
     return breakdown, diverged
 
 
+@scope("control")
 def pcg_pipelined(
     matvec: MatVec,
     b: Vec,
@@ -487,6 +497,7 @@ def pcg_pipelined(
                        _iters_like(b, iters), status, bad)
 
 
+@scope("control")
 def pcg_pipelined_tol(
     matvec: MatVec,
     b: Vec,
@@ -623,6 +634,7 @@ def pcg_pipelined_tol(
     return SolveResult(x, trace, it, status, bad)
 
 
+@scope("control")
 def pcg_tol(
     matvec: MatVec,
     b: Vec,
@@ -765,6 +777,7 @@ def pcg_tol(
     return SolveResult(x, trace, it, status, bad)
 
 
+@scope("control")
 def jacobi(
     matvec: MatVec,
     diag_inv: Vec,
@@ -783,7 +796,8 @@ def jacobi(
 
     def step(x, _):
         r = b - matvec(x)
-        x = x + diag_inv * r
+        with scope("update"):
+            x = x + diag_inv * r
         return x, _norm(dot(r, r))
 
     x, norms = lax.scan(step, x, None, length=iters)

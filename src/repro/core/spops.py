@@ -4,6 +4,8 @@ These are the *functional* definitions of the engine's math; the Pallas
 kernels in ``repro.kernels`` implement the same contracts with explicit VMEM
 tiling and are verified against these (plus numpy/scipy) in tests.  The
 distributed engine composes these per-tile ops under ``shard_map``.
+The matvecs run under the ``matvec`` scope and their gathers of x by
+column id under ``gather`` (``repro.obs.scopes``).
 """
 
 from __future__ import annotations
@@ -12,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.scopes import scope
 from .formats import ELL, BCSR, HYB, SELL
 from .levels import LevelSchedule
 
@@ -37,69 +40,95 @@ def spmv_ell(m: ELL, x: jnp.ndarray) -> jnp.ndarray:
     return spmv_ell_padded(m.cols, m.vals, x)[: m.n_rows]
 
 
+@scope("matvec")
 def spmv_ell_padded(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """Padded-row SpMV: (rows_p, w) gather + row-sum.  Padding vals are 0 so
     padded slots contribute nothing; padded cols point at 0 which is always
     in-bounds."""
-    return jnp.sum(vals * x[cols], axis=1)
+    with scope("gather"):
+        xg = x[cols]
+    return jnp.sum(vals * xg, axis=1)
 
 
+@scope("matvec")
 def spmm_ell_padded(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """Batched multi-RHS SpMV in the solvers' stacked layout: x is (k, n),
     returns (k, rows_p).  One gather of the matrix serves all k vectors --
     x[:, cols] is (k, rows_p, w), weighted by the shared (rows_p, w) vals."""
-    return jnp.sum(vals * x[:, cols], axis=-1)
+    with scope("gather"):
+        xg = x[:, cols]
+    return jnp.sum(vals * xg, axis=-1)
 
 
+@scope("matvec")
 def spmv_sell_flat(m: SELL, x: jnp.ndarray) -> jnp.ndarray:
     """Padded-row SpMV over sliced-ELL flat storage: one gather of x per
     stored entry, then a segment-sum by row id.  Returns (rows_padded,)
     (padded rows reduce only their own 0.0 padding entries)."""
+    with scope("gather"):
+        xg = x[m.cols]
     return jax.ops.segment_sum(
-        m.vals * x[m.cols], m.rows, num_segments=m.rows_padded
+        m.vals * xg, m.rows, num_segments=m.rows_padded
     )
 
 
+@scope("matvec")
 def spmm_sell_flat(m: SELL, x: jnp.ndarray) -> jnp.ndarray:
     """Multi-RHS sliced-ELL SpMV in the solvers' stacked layout: x is
     (k, n_pad), returns (k, rows_padded).  One matrix stream serves all k
     (the segment reduction runs over the leading entry axis)."""
-    contrib = m.vals * x[:, m.cols]             # (k, n_stored)
+    with scope("gather"):
+        xg = x[:, m.cols]
+    contrib = m.vals * xg                       # (k, n_stored)
     return jax.ops.segment_sum(
         contrib.T, m.rows, num_segments=m.rows_padded
     ).T
 
 
+@scope("matvec")
 def spmv_hyb_padded(m: HYB, x: jnp.ndarray) -> jnp.ndarray:
     """HYB SpMV: the regular ELL-core gather + row-sum, then a COO
     scatter-add of the spill tail.  Returns (rows_padded,)."""
-    y = jnp.sum(m.vals * x[m.cols], axis=1)
-    return y.at[m.tail_rows].add(m.tail_vals * x[m.tail_cols])
+    with scope("gather"):
+        xg = x[m.cols]
+    y = jnp.sum(m.vals * xg, axis=1)
+    with scope("gather"):
+        xt = x[m.tail_cols]
+    return y.at[m.tail_rows].add(m.tail_vals * xt)
 
 
+@scope("matvec")
 def spmm_hyb_padded(m: HYB, x: jnp.ndarray) -> jnp.ndarray:
     """Multi-RHS HYB SpMV: x is (k, n_pad), returns (k, rows_padded)."""
-    y = jnp.sum(m.vals * x[:, m.cols], axis=-1)
-    return y.at[:, m.tail_rows].add(m.tail_vals * x[:, m.tail_cols])
+    with scope("gather"):
+        xg = x[:, m.cols]
+    y = jnp.sum(m.vals * xg, axis=-1)
+    with scope("gather"):
+        xt = x[:, m.tail_cols]
+    return y.at[:, m.tail_rows].add(m.tail_vals * xt)
 
 
+@scope("matvec")
 def spmv_bcsr(m: BCSR, x: jnp.ndarray) -> jnp.ndarray:
     """y = A @ x for BCSR A (dense (bm, bn) blocks -> MXU-shaped einsum)."""
     nbc = (m.n_cols + m.bn - 1) // m.bn
     x_pad = jnp.zeros((nbc * m.bn,), x.dtype).at[: m.n_cols].set(x)
     xb = x_pad.reshape(nbc, m.bn)
-    xg = xb[m.block_cols]                      # (nbr, width, bn)
+    with scope("gather"):
+        xg = xb[m.block_cols]                  # (nbr, width, bn)
     y = jnp.einsum("iwmn,iwn->im", m.blocks, xg)  # (nbr, bm)
     return y.reshape(-1)[: m.n_rows]
 
 
+@scope("matvec")
 def spmv_bcsr_padded(m: BCSR, x: jnp.ndarray, n_pad: int) -> jnp.ndarray:
     """BCSR SpMV on padded engine vectors: x is (n_pad,), returns (n_pad,).
     x re-embeds into the (nbc*bn,) block layout, blocks apply as dense
     (bm, bn) fmas, and the (nbr*bm,) result re-embeds into n_pad."""
     nbc = (m.n_cols + m.bn - 1) // m.bn
     x_blk = jnp.zeros((nbc * m.bn,), x.dtype).at[: m.n_cols].set(x[: m.n_cols])
-    xg = x_blk.reshape(nbc, m.bn)[m.block_cols]      # (nbr, width, bn)
+    with scope("gather"):
+        xg = x_blk.reshape(nbc, m.bn)[m.block_cols]  # (nbr, width, bn)
     y = jnp.einsum("iwmn,iwn->im", m.blocks, xg).reshape(-1)
     nbr_rows = y.shape[0]
     if nbr_rows >= n_pad:
@@ -107,6 +136,7 @@ def spmv_bcsr_padded(m: BCSR, x: jnp.ndarray, n_pad: int) -> jnp.ndarray:
     return jnp.zeros((n_pad,), y.dtype).at[:nbr_rows].set(y)
 
 
+@scope("matvec")
 def spmm_bcsr_padded(m: BCSR, x: jnp.ndarray, n_pad: int) -> jnp.ndarray:
     """Multi-RHS BCSR SpMV: x is (k, n_pad), returns (k, n_pad) -- one
     block stream for all k (the einsum carries the batch axis)."""
@@ -114,7 +144,8 @@ def spmm_bcsr_padded(m: BCSR, x: jnp.ndarray, n_pad: int) -> jnp.ndarray:
     k = x.shape[0]
     x_blk = jnp.zeros((k, nbc * m.bn), x.dtype).at[:, : m.n_cols].set(
         x[:, : m.n_cols])
-    xg = x_blk.reshape(k, nbc, m.bn)[:, m.block_cols]   # (k, nbr, width, bn)
+    with scope("gather"):
+        xg = x_blk.reshape(k, nbc, m.bn)[:, m.block_cols]  # (k, nbr, w, bn)
     y = jnp.einsum("iwmn,kiwn->kim", m.blocks, xg).reshape(k, -1)
     nbr_rows = y.shape[1]
     if nbr_rows >= n_pad:

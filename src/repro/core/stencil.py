@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.scopes import scope
+
 __all__ = [
     "Stencil",
     "lap2d_stencil",
@@ -105,6 +107,7 @@ def _axis_1d(u: jnp.ndarray, axis: int) -> jnp.ndarray:
     return 2.0 * u - dn - up
 
 
+@scope("matvec")
 def stencil_matvec(st: Stencil, x: jnp.ndarray, n_pad: int | None = None) -> jnp.ndarray:
     """y = A x for the stencil operator on padded solver vectors.
 

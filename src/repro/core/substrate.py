@@ -73,6 +73,12 @@ which implementation backs it is a deployment decision:
   the same collective fusion with the per-tile block-IC(0) triangular
   solves as the local psolve.
 
+Every op runs under its ``repro.obs.scopes`` layer: ``precond`` for each
+``psolve``, ``update`` for the vector updates (and the p-update where it
+is not folded into an ELL gather), ``reduce`` for the dots and their
+``psum``s; the matvecs carry ``matvec``/``gather`` from where they are
+built.
+
 The traffic models behind the fusions (see README "Performance") are
 exposed as :func:`modeled_vector_traffic` / :func:`modeled_ic0_traffic` so
 benchmarks can record them.
@@ -86,6 +92,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import ops
+from ..obs.scopes import scope
 from . import spops
 
 __all__ = [
@@ -102,6 +109,7 @@ __all__ = [
 ]
 
 
+@scope("reduce")
 def _dot(u, v):
     """Solver dot convention: () for (n,), (k, 1) for (k, n) batches."""
     return jnp.sum(u * v, axis=-1, keepdims=u.ndim > 1)
@@ -126,6 +134,7 @@ class SolverSubstrate(NamedTuple):
     matvec_finish: Callable | None = None
 
 
+@scope("update")
 def pipe_update(beta, alpha, x, r, u, w, z, q, s, p, m, n):
     """The Chronopoulos-Gear one-pass 8-vector update.
 
@@ -151,6 +160,7 @@ def pipe_update(beta, alpha, x, r, u, w, z, q, s, p, m, n):
 def _pipe_dots_local(dot):
     """Local stacked [gamma, delta, rr] (no collective)."""
 
+    @scope("reduce")
     def pipe_dots(r, u, w):
         return jnp.stack([dot(r, u), dot(w, u), dot(r, r)])
 
@@ -160,6 +170,7 @@ def _pipe_dots_local(dot):
 def _pipe_dots_shard(psum):
     """Shard flavor: all three partials ride ONE stacked psum."""
 
+    @scope("reduce")
     def pipe_dots(r, u, w):
         return psum(jnp.stack([_dot(r, u), _dot(w, u), _dot(r, r)]))
 
@@ -171,20 +182,26 @@ def reference_substrate(matvec, psolve, dot=None) -> SolverSubstrate:
     the verification oracle and for preconditioners without a fused path."""
     dot = dot or _dot
 
+    @scope("precond")
+    def ps(r):
+        return psolve(r)
+
     def fold_matvec_dot(z, p, beta):
-        p = z + beta * p
+        with scope("update"):
+            p = z + beta * p
         ap = matvec(p)
         return p, ap, dot(p, ap)
 
+    @scope("update")
     def update(alpha, x, r, p, ap):
         x = x + alpha * p
         r = r - alpha * ap
-        z = psolve(r)
+        z = ps(r)
         rz = dot(r, z)
         rr = dot(r, r)
         return x, r, z, rr, rz
 
-    return SolverSubstrate("reference", matvec, psolve, dot,
+    return SolverSubstrate("reference", matvec, ps, dot,
                            fold_matvec_dot, update,
                            pipe_dots=_pipe_dots_local(dot),
                            pipe_update=pipe_update)
@@ -194,8 +211,10 @@ def _ell_stream_ops(cols, vals):
     """The shared ELL-operator pair (matvec, fold_matvec_dot) for local
     fused substrates: Pallas kernels when active, the fused jnp
     composition otherwise.  Vectors arrive in solver layout ((n,) or
-    (k, n)); kernel calls transpose to the (n, k) kernel layout."""
+    (k, n)); kernel calls transpose to the (n, k) kernel layout.  The
+    jnp p-fold is scoped ``gather``, like the kernel path's."""
 
+    @scope("matvec")
     def matvec(v):
         if v.ndim == 2:
             if ops.kernels_active():
@@ -203,6 +222,7 @@ def _ell_stream_ops(cols, vals):
             return spops.spmm_ell_padded(cols, vals, v)
         return ops.ell_spmv(cols, vals, v)
 
+    @scope("matvec")
     def fold_matvec_dot(z, p, beta):
         if z.ndim == 2:
             if ops.kernels_active():
@@ -210,12 +230,14 @@ def _ell_stream_ops(cols, vals):
                     cols, vals, z.T, p.T, jnp.reshape(beta, (-1,))
                 )
                 return pn.T, y.T, pap[:, None]
-            pn = z + beta * p
+            with scope("gather"):
+                pn = z + beta * p
             y = spops.spmm_ell_padded(cols, vals, pn)
             return pn, y, _dot(pn, y)
         if ops.kernels_active():
             return ops.ell_spmv_pfold_dot(cols, vals, z, p, beta)
-        pn = z + beta * p
+        with scope("gather"):
+            pn = z + beta * p
         y = spops.spmv_ell_padded(cols, vals, pn)
         return pn, y, _dot(pn, y)
 
@@ -229,7 +251,8 @@ def _fold_from_matvec(matvec):
     kernel folds for the compact formats are a TPU follow-up (ROADMAP)."""
 
     def fold_matvec_dot(z, p, beta):
-        pn = z + beta * p
+        with scope("update"):
+            pn = z + beta * p
         y = matvec(pn)
         return pn, y, _dot(pn, y)
 
@@ -270,6 +293,7 @@ def format_stream_ops(fmt_obj, fmt: str, n_pad: int):
     elif fmt == "bcsr":
         nbc = (fmt_obj.n_cols + fmt_obj.bn - 1) // fmt_obj.bn
 
+        @scope("matvec")
         def matvec(v):
             if ops.kernels_active():
                 # kernel layout: x is (nbc*bn, k); embed the padded solver
@@ -309,6 +333,7 @@ def fused_local_substrate(cols, vals, dinv=None, stream_ops=None) -> SolverSubst
     matvec, fold_matvec_dot = (stream_ops if stream_ops is not None
                                else _ell_stream_ops(cols, vals))
 
+    @scope("precond")
     def psolve(r):
         return r * dinv if dinv is not None else r
 
@@ -342,6 +367,7 @@ def fused_ic0_local_substrate(cols, vals, factors, n: int,
     # (n_pad,) residual -> (z (n_pad,), rz scalar), fully fused
     _apply_dot = make_fused_ic0_apply(factors, n, n_pad, vals.dtype)
 
+    @scope("precond")
     def psolve(r):
         if r.ndim == 2:
             return jax.vmap(lambda v: _apply_dot(v)[0])(r)
@@ -351,11 +377,12 @@ def fused_ic0_local_substrate(cols, vals, factors, n: int,
         # one-pass x/r update + rr (identity z discarded), then the fused
         # two-solve preconditioner application with rz in-stream
         xo, ro, _, rr, _ = ops.cg_update(alpha, x, r, p, ap, None)
-        if ro.ndim == 2:
-            z, rz = jax.vmap(_apply_dot)(ro)
-            return xo, ro, z, rr, rz[:, None]
-        z, rz = _apply_dot(ro)
-        return xo, ro, z, rr, rz
+        with scope("precond"):
+            if ro.ndim == 2:
+                z, rz = jax.vmap(_apply_dot)(ro)
+                return xo, ro, z, rr, rz[:, None]
+            z, rz = _apply_dot(ro)
+            return xo, ro, z, rr, rz
 
     return SolverSubstrate("fused_ic0", matvec, psolve, _dot,
                            fold_matvec_dot, update,
@@ -375,13 +402,15 @@ def _shard_stream_ops(matvec, psum):
     the halo-extended p carried across iterations (a TPU follow-up, see
     ROADMAP); the fused win here is collective fusion (flavors below)."""
 
+    @scope("reduce")
     def dot(u, v):
         return psum(_dot(u, v))
 
     def fold_matvec_dot(z, p, beta):
-        p = z + beta * p                 # folded update, inside the closure
+        with scope("update"):
+            p = z + beta * p             # folded update, inside the closure
         ap = matvec(p)                   # halo exchange (or dense gather)
-        return p, ap, psum(_dot(p, ap))
+        return p, ap, dot(p, ap)
 
     return dot, fold_matvec_dot
 
@@ -402,12 +431,14 @@ def fused_shard_substrate(matvec, dinv, psum) -> SolverSubstrate:
 
     dot, fold_matvec_dot = _shard_stream_ops(matvec, psum)
 
+    @scope("precond")
     def psolve(r):
         return r * dinv if dinv is not None else r
 
     def update(alpha, x, r, p, ap):
         x, r, z, rr, rz = ops.cg_update(alpha, x, r, p, ap, dinv)
-        s = psum(jnp.stack([rr, rz]))      # ONE collective for both dots
+        with scope("reduce"):
+            s = psum(jnp.stack([rr, rz]))  # ONE collective for both dots
         return x, r, z, s[0], s[1]
 
     return SolverSubstrate("fused_shard", matvec, psolve, dot,
@@ -426,14 +457,19 @@ def fused_shard_ic0_substrate(matvec, psolve_local, psum) -> SolverSubstrate:
 
     dot, fold_matvec_dot = _shard_stream_ops(matvec, psum)
 
+    @scope("precond")
+    def psolve(r):
+        return psolve_local(r)
+
     def update(alpha, x, r, p, ap):
         xo, ro, _, rr, _ = ops.cg_update(alpha, x, r, p, ap, None)
-        z = psolve_local(ro)
+        z = psolve(ro)
         rz = _dot(ro, z)
-        s = psum(jnp.stack([rr, rz]))      # ONE collective for both dots
+        with scope("reduce"):
+            s = psum(jnp.stack([rr, rz]))  # ONE collective for both dots
         return xo, ro, z, s[0], s[1]
 
-    return SolverSubstrate("fused_shard_ic0", matvec, psolve_local, dot,
+    return SolverSubstrate("fused_shard_ic0", matvec, psolve, dot,
                            fold_matvec_dot, update,
                            pipe_dots=_pipe_dots_shard(psum),
                            pipe_update=pipe_update)

@@ -35,6 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from ..obs.scopes import scope
+
 __all__ = ["ell_spmv", "ell_spmm", "stream_rows"]
 
 DEFAULT_TM = 8192
@@ -48,7 +50,8 @@ def lane_major(cols, vals, xs):
     """(vals^T (w, rows_p), xs[:, cols^T] (k, w, rows_p)): the two planes
     the kernel streams.  ``xs`` is (k, m) -- k stacked vectors of any
     length m the column ids index into (the halo buffer on a tile)."""
-    return vals.T, xs[:, cols.T]
+    with scope("gather"):
+        return vals.T, xs[:, cols.T]
 
 
 def row_tiles(rows_p: int, w: int, tm: int, tw: int):

@@ -10,6 +10,10 @@ tests, so the choice is purely a performance/backend decision.
 mode can be set with the ``REPRO_KERNEL_MODE`` environment variable (used
 by the CI bench smoke job to exercise kernels on CPU runners).
 
+Each op runs under the ``repro.obs.scopes`` scope of its layer (the ELL
+and BCSR streams ``matvec``, the triangular solves ``precond``, the vector
+updates ``update``), on the kernel and the jnp path alike.
+
 Tile selection: explicit tile args always win; otherwise the wrappers
 consult the autotune cache (``autotune.py``, populated by
 ``bench_kernels --autotune``) for this op/shape/dtype/backend, and finally
@@ -25,6 +29,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from ..obs.scopes import scope
 from . import autotune, ref
 from .ell_spmv import ell_spmv as _ell_spmv_pallas
 from .ell_spmv import ell_spmm as _ell_spmm_pallas
@@ -145,6 +150,7 @@ def _tiles_2d(op: str, cols, dtype, tm, tw, k: int = 1):
     return tm, tw
 
 
+@scope("matvec")
 def ell_spmv(cols, vals, x, tm: int | None = None, tw: int | None = None):
     use, interp = _dispatch()
     if use:
@@ -153,6 +159,7 @@ def ell_spmv(cols, vals, x, tm: int | None = None, tw: int | None = None):
     return ref.ell_spmv_ref(cols, vals, x)
 
 
+@scope("matvec")
 def ell_spmm(cols, vals, x, tm: int | None = None, tw: int | None = None):
     """Multi-RHS SpMM; x is (n, k) dense, one matrix stream for all k."""
     use, interp = _dispatch()
@@ -162,6 +169,7 @@ def ell_spmm(cols, vals, x, tm: int | None = None, tw: int | None = None):
     return ref.ell_spmm_ref(cols, vals, x)
 
 
+@scope("matvec")
 def ell_spmv_dot(cols, vals, x, tm: int | None = None, tw: int | None = None):
     """Fused SpMV + dot: (y, pap) = (A @ x, dot(x, y)) in one matrix pass."""
     use, interp = _dispatch()
@@ -171,6 +179,7 @@ def ell_spmv_dot(cols, vals, x, tm: int | None = None, tw: int | None = None):
     return ref.ell_spmv_dot_ref(cols, vals, x)
 
 
+@scope("matvec")
 def ell_spmm_dot(cols, vals, x, tm: int | None = None, tw: int | None = None):
     """Multi-RHS fused SpMM + dot; x (n, k) -> (Y (n, k), pap (k,))."""
     use, interp = _dispatch()
@@ -181,6 +190,7 @@ def ell_spmm_dot(cols, vals, x, tm: int | None = None, tw: int | None = None):
     return ref.ell_spmm_dot_ref(cols, vals, x)
 
 
+@scope("matvec")
 def ell_spmv_pfold_dot(cols, vals, z, p, beta,
                        tm: int | None = None, tw: int | None = None):
     """p-fold SpMV + dot: p' = z + beta*p at gather time, y = A @ p',
@@ -193,6 +203,7 @@ def ell_spmv_pfold_dot(cols, vals, z, p, beta,
     return ref.ell_spmv_pfold_dot_ref(cols, vals, z, p, beta)
 
 
+@scope("matvec")
 def ell_spmm_pfold_dot(cols, vals, z, p, beta,
                        tm: int | None = None, tw: int | None = None):
     """Multi-RHS p-fold (kernel layout (n, k), beta (k,))."""
@@ -205,6 +216,7 @@ def ell_spmm_pfold_dot(cols, vals, z, p, beta,
     return ref.ell_spmm_pfold_dot_ref(cols, vals, z, p, beta)
 
 
+@scope("matvec")
 def bcsr_spmm(block_cols, blocks, x, nbc: int | None = None):
     """Block-sparse x dense multi-RHS (the MXU path); ``nbc`` (static)
     asserts x is exactly (nbc*bn, R) -- see ``bcsr_spmm.bcsr_spmm``."""
@@ -219,6 +231,7 @@ def bcsr_spmm(block_cols, blocks, x, nbc: int | None = None):
     return ref.bcsr_spmm_ref(block_cols, blocks, x)
 
 
+@scope("precond")
 def sptrsv_level_step(cols, vals, diag, b, x, level_rows, tl: int | None = None):
     """Level wavefront: gathers rows, runs the kernel (or ref), scatters."""
     use, interp = _dispatch()
@@ -269,6 +282,7 @@ def sptrsv_solve_pack(cols, vals, dinv, sched_rows, n_rows: int) -> dict:
     }
 
 
+@scope("precond")
 def sptrsv_solve_dot(cols, vals, dinv, b, sched_rows, wdot=None,
                      n_rows: int | None = None, tl: int | None = None,
                      pack: dict | None = None):
@@ -308,6 +322,7 @@ def sptrsv_solve_dot(cols, vals, dinv, b, sched_rows, wdot=None,
     return x, pp
 
 
+@scope("update")
 def axpy_dot(a, x, y, tn: int | None = None):
     use, interp = _dispatch()
     if use:
@@ -319,6 +334,7 @@ def axpy_dot(a, x, y, tn: int | None = None):
     return ref.axpy_dot_ref(a, x, y)
 
 
+@scope("update")
 def cg_update(alpha, x, r, p, ap, dinv=None, tn: int | None = None):
     """One-pass CG update (see ``vecops.cg_update``): handles arbitrary n
     via masked tail tiles, (k, n) batches via per-RHS alphas."""

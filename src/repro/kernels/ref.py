@@ -8,6 +8,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from ..obs.scopes import scope
+
 __all__ = [
     "ell_spmv_ref", "ell_spmm_ref", "bcsr_spmm_ref",
     "sptrsv_level_step_ref", "sptrsv_solve_dot_ref", "axpy_dot_ref",
@@ -18,7 +20,9 @@ __all__ = [
 
 def ell_spmv_ref(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """y[r] = sum_k vals[r, k] * x[cols[r, k]].  Padding: vals == 0."""
-    return jnp.sum(vals * x[cols], axis=1)
+    with scope("gather"):
+        xg = x[cols]
+    return jnp.sum(vals * xg, axis=1)
 
 
 def ell_spmm_ref(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
@@ -26,7 +30,9 @@ def ell_spmm_ref(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray) -> jnp.nd
 
     Y[r, :] = sum_w vals[r, w] * x[cols[r, w], :] -- one matrix read shared
     by all k right-hand sides."""
-    return jnp.sum(vals[..., None] * x[cols], axis=1)
+    with scope("gather"):
+        xg = x[cols]
+    return jnp.sum(vals[..., None] * xg, axis=1)
 
 
 def bcsr_spmm_ref(block_cols: jnp.ndarray, blocks: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
@@ -39,7 +45,8 @@ def bcsr_spmm_ref(block_cols: jnp.ndarray, blocks: jnp.ndarray, x: jnp.ndarray) 
     """
     nbr, w, bm, bn = blocks.shape
     xr = x.reshape(-1, bn, x.shape[-1])          # (nbc, bn, R)
-    xg = xr[block_cols]                          # (nbr, w, bn, R)
+    with scope("gather"):
+        xg = xr[block_cols]                      # (nbr, w, bn, R)
     y = jnp.einsum("iwmn,iwnr->imr", blocks, xg)
     return y.reshape(nbr * bm, x.shape[-1])
 
@@ -116,31 +123,43 @@ def axpy_dot_ref(a, x: jnp.ndarray, y: jnp.ndarray):
 def ell_spmv_dot_ref(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray):
     """Fused SpMV + dot: (y, pap) = (A @ x, dot(x, y)) -- square padded
     operator, x.shape == (rows_p,)."""
-    y = jnp.sum(vals * x[cols], axis=1)
-    return y, jnp.sum(x * y)
+    with scope("gather"):
+        xg = x[cols]
+    y = jnp.sum(vals * xg, axis=1)
+    with scope("reduce"):
+        return y, jnp.sum(x * y)
 
 
 def ell_spmm_dot_ref(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray):
     """Multi-RHS fused SpMM + dot in kernel layout: x (rows_p, k) dense ->
     (Y, pap) with Y = A @ X (rows_p, k), pap[j] = dot(X[:, j], Y[:, j])."""
-    y = jnp.sum(vals[..., None] * x[cols], axis=1)
-    return y, jnp.sum(x * y, axis=0)
+    with scope("gather"):
+        xg = x[cols]
+    y = jnp.sum(vals[..., None] * xg, axis=1)
+    with scope("reduce"):
+        return y, jnp.sum(x * y, axis=0)
 
 
 def ell_spmv_pfold_dot_ref(cols, vals, z, p, beta):
     """p-fold contract: p' = z + beta*p computed at gather time, then
     (p', y, pap) = (p', A @ p', dot(p', y)) from the one matrix stream."""
-    pn = z + beta * p
-    y = jnp.sum(vals * pn[cols], axis=1)
-    return pn, y, jnp.sum(pn * y)
+    with scope("gather"):
+        pn = z + beta * p
+        pg = pn[cols]
+    y = jnp.sum(vals * pg, axis=1)
+    with scope("reduce"):
+        return pn, y, jnp.sum(pn * y)
 
 
 def ell_spmm_pfold_dot_ref(cols, vals, z, p, beta):
     """Multi-RHS p-fold in kernel layout: z/p (rows_p, k), beta (k,).
     Returns (p', Y, pap) with pap[j] = dot(p'[:, j], Y[:, j])."""
-    pn = z + jnp.reshape(beta, (1, -1)) * p
-    y = jnp.sum(vals[..., None] * pn[cols], axis=1)
-    return pn, y, jnp.sum(pn * y, axis=0)
+    with scope("gather"):
+        pn = z + jnp.reshape(beta, (1, -1)) * p
+        pg = pn[cols]
+    y = jnp.sum(vals[..., None] * pg, axis=1)
+    with scope("reduce"):
+        return pn, y, jnp.sum(pn * y, axis=0)
 
 
 def cg_update_ref(alpha, x, r, p, ap, dinv=None):

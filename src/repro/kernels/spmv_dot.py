@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..obs.scopes import scope
 from .ell_spmv import _Z, DEFAULT_TM, DEFAULT_TW, lane_major, row_tiles
 
 __all__ = ["ell_spmv_dot", "ell_spmm_dot", "ell_spmv_pfold_dot",
@@ -102,6 +103,8 @@ def stream_rows_dot(cols, vals, xs, tm: int = DEFAULT_TM,
     tm, tw, grid = row_tiles(rows_p, w, tm, tw)
     pw = partial_width(tm)
     vt, xg = lane_major(cols, vals, xs)
+    # no scope between the jit and the kernel: the kernel's instruction is
+    # named after the innermost name on the stack (stream_rows_dot)
     y, partials = pl.pallas_call(
         functools.partial(_rows_dot_kernel, rows_p=rows_p),
         grid=grid,
@@ -120,7 +123,8 @@ def stream_rows_dot(cols, vals, xs, tm: int = DEFAULT_TM,
         ],
         interpret=interpret,
     )(vt, xg, xs)
-    return y, jnp.sum(partials, axis=1)
+    with scope("reduce"):
+        return y, jnp.sum(partials, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "tw", "interpret"))
@@ -181,7 +185,8 @@ def ell_spmv_pfold_dot(
             f"ell_spmv_pfold_dot needs square padded vectors: z {z.shape} / "
             f"p {p.shape} vs rows {rows_p}"
         )
-    pn = z + jnp.asarray(beta, vals.dtype) * p
+    with scope("gather"):
+        pn = z + jnp.asarray(beta, vals.dtype) * p
     y, pap = stream_rows_dot(cols, vals, pn[None], tm=tm, tw=tw,
                              interpret=interpret)
     return pn, y[0], pap[0]
@@ -209,7 +214,8 @@ def ell_spmm_pfold_dot(
             f"ell_spmm_pfold_dot needs square padded vectors: z {z.shape} / "
             f"p {p.shape} vs rows {rows_p}"
         )
-    pn = z + jnp.reshape(jnp.asarray(beta, vals.dtype), (1, -1)) * p
+    with scope("gather"):
+        pn = z + jnp.reshape(jnp.asarray(beta, vals.dtype), (1, -1)) * p
     y, pap = stream_rows_dot(cols, vals, pn.T, tm=tm, tw=tw,
                              interpret=interpret)
     return pn, y.T, pap

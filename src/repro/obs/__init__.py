@@ -3,18 +3,25 @@
 The measurement substrate for solves, kernels, and the serving plane
 (ROADMAP: the management-plane counterpart to the PR 8 service).  Three
 layers, all host-side (an instrumented solve is bitwise identical to a
-bare one -- asserted in ``tests/test_obs.py``):
+bare one -- asserted in ``tests/test_obs.py``), plus the one piece that
+lives in the device program, as metadata only:
 
 * :mod:`repro.obs.metrics` -- process-local registry (:data:`REGISTRY`)
   of labeled counters, gauges and log-bucket histograms; cheap enough to
   leave always-on, with :func:`set_enabled` / :func:`disabled` as the
   kill switch the overhead benchmark measures against.
-* :mod:`repro.obs.trace` -- span ring buffer (:data:`TRACER`): solve /
-  chunk / plan-build / tick spans, Chrome trace-event export, optional
-  ``jax.profiler`` bridge.
+* :mod:`repro.obs.trace` -- span ring buffer (:data:`TRACER`): solve
+  (with its stage-in / execute / stage-out children) / chunk / plan-build
+  / plan-compile / tick spans with parent and request ids, Chrome
+  trace-event export, optional ``jax.profiler`` bridge.
 * :mod:`repro.obs.export` -- Prometheus text exposition, JSON snapshots,
   and the stdlib HTTP ``/metrics`` endpoint
   (``launch/serve.py --metrics-port``).
+* :mod:`repro.obs.scopes` -- the closed vocabulary of ``jax.named_scope``
+  layers the solve programs are built under (gather, matvec, precond,
+  update, reduce, halo, control) and :data:`SCOPES`, the HLO
+  instruction -> scope map of every compiled plan, which names a
+  profiler trace's device operations by layer.
 
 Plus :mod:`repro.obs.clock`: the ONE injectable monotonic clock every
 host-side timing path reads (``serve``, ``ft``, the load generator) --

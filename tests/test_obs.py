@@ -236,16 +236,21 @@ def test_step_timer_straggler_detection_deterministic():
 def test_chrome_trace_export(tmp_path):
     tracer = obs.Tracer()
     with obs.clock.override(FakeClock(start=1.0)) as fake:
-        with tracer.span("solve", kind="solve", matrix="lap2d_16"):
-            fake.advance(0.5)
+        with tracer.span("solve", kind="solve", matrix="lap2d_16") as s:
+            with tracer.span("solve.execute"):
+                fake.advance(0.5)
     path = tmp_path / "trace.json"
-    assert tracer.export_chrome(str(path)) == 1
+    assert tracer.export_chrome(str(path)) == 2
     import json
 
-    ev = json.loads(path.read_text())["traceEvents"][0]
+    child, ev = json.loads(path.read_text())["traceEvents"]
     assert ev == {"name": "solve", "cat": "solve", "ph": "X",
                   "ts": 1.0e6, "dur": 0.5e6, "pid": 0, "tid": 0,
-                  "args": {"matrix": "lap2d_16"}}
+                  "args": {"matrix": "lap2d_16", "id": s.id,
+                           "parent": None, "request": s.id}}
+    assert child["name"] == "solve.execute"
+    assert child["args"]["parent"] == child["args"]["request"] == s.id
+    assert child["args"]["id"] != s.id
 
 
 # -- HTTP exposition ----------------------------------------------------------

@@ -133,3 +133,42 @@ def test_ell_tiles_are_lane_multiples():
         assert tm % 128 == 0 and tw == W
     assert ops._lane_tile(1000, 8192) == 1000
     assert ops._lane_tile(10**6, 1000) == 896
+
+
+@pytest.mark.parametrize("kind", ["stored", "stencil"])
+def test_plan_layers_keep_their_scopes_for_v5e(kind, one_chip,
+                                               compiled_kernels):
+    """In the v5e compile of a whole Jacobi-PCG plan, the XLA gather
+    fusion, the ELL kernel, the cg_update kernel and the solver loop are
+    named by their ``repro.obs.scopes`` layer (small grids: the program's
+    structure does not depend on n)."""
+    import re
+
+    from repro.core import AzulEngine, SolveSpec
+    from repro.core.stencil import lap3d_stencil
+    from repro.data.matrices import laplacian_2d
+    from repro.obs.scopes import parse_hlo
+
+    a = laplacian_2d(128) if kind == "stored" else lap3d_stencil(32)
+    eng = AzulEngine(a, precond="jacobi", dtype=np.float32)
+    plan = eng.plan(SolveSpec(method="pcg_tol", tol=1e-5, max_iters=8000))
+    vec = _sds(one_chip, (eng.n_pad,))
+    text = plan.fn.lower(vec, vec).compile().as_text()
+    found = parse_hlo(text)
+
+    def names(pattern):
+        return [m.group(1) for m in re.finditer(
+            r"^\s*(?:ROOT\s+)?%(\S+) = [^\n]*" + pattern, text, re.M)]
+
+    assert {found[n] for n in names(r" while\(")} == {"control"}
+    cg = [n for n in names(r'custom_call_target="tpu_custom_call"')
+          if n.startswith("cg_update.")]
+    assert cg and {found[n] for n in cg} == {"update"}
+    gathers = names(r" fusion\([^\n]*kind=kCustom")
+    if kind == "stored":
+        assert gathers and {found[n] for n in gathers} == {"gather"}
+        ell = [n for n in names(r'custom_call_target="tpu_custom_call"')
+               if n.startswith("stream_rows")]
+        assert ell and {found[n] for n in ell} == {"matvec"}
+    else:
+        assert not gathers
