@@ -99,6 +99,13 @@ from .substrate import (format_stream_ops, fused_ic0_local_substrate,
 
 __all__ = ["AzulEngine", "local_sptrsv"]
 
+_M_STAGED_COPIES = _OBS.counter(
+    "repro_solve_staged_copies_total",
+    "vectors the engine staged through a host padding or permutation copy "
+    "('in': to_device_vec, 'out': from_device_vec); an unpadded, "
+    "unpermuted vector goes straight across and is not counted",
+    ("phase",))
+
 
 def _shard_map(f, mesh, in_specs, out_specs):
     """``jax.shard_map`` with replication checking off: the solver programs
@@ -574,24 +581,41 @@ class AzulEngine:
         replicate the batch axis, so k RHS share one set of matrix blocks.
         With ``reorder`` active the engine's row permutation applies here
         (and inverts in :meth:`from_device_vec`), so callers always speak
-        the original ordering."""
+        the original ordering.
+
+        Where the layout is the identity (no permutation, no pad2g map,
+        ``n_pad == n``) the vector goes to the device as it is, after one
+        ``astype`` if its dtype is not the engine's.  Otherwise it is built
+        into a fresh zero-filled host buffer, never reused across calls (on
+        the CPU backend a device array may alias its host memory), and
+        counted in ``repro_solve_staged_copies_total{phase="in"}``."""
         v = np.asarray(v)
-        if self._row_perm is not None:
-            v = v[..., self._row_perm]
-        out = np.zeros(v.shape[:-1] + (self.n_pad,), self.dtype)
-        if self._pad2g is not None:
-            valid = self._pad2g < self.n
-            out[..., valid] = v[..., self._pad2g[valid]]
+        if (self._row_perm is None and self._pad2g is None
+                and self.n_pad == self.n and v.shape[-1] == self.n):
+            out = v if v.dtype == self.dtype else v.astype(self.dtype)
         else:
-            out[..., : self.n] = v
+            _M_STAGED_COPIES.inc(phase="in")
+            if self._row_perm is not None:
+                v = v[..., self._row_perm]
+            out = np.zeros(v.shape[:-1] + (self.n_pad,), self.dtype)
+            if self._pad2g is not None:
+                valid = self._pad2g < self.n
+                out[..., valid] = v[..., self._pad2g[valid]]
+            else:
+                out[..., : self.n] = v
         if self.mesh is None:
             return jnp.asarray(out)
         spec = self._bvec_spec if v.ndim == 2 else self._vec_spec
         return self._put(out, spec)
 
     def from_device_vec(self, v: jnp.ndarray) -> np.ndarray:
-        """Extract the global (n,) / (k, n) vector from the padded layout."""
+        """Extract the global (n,) / (k, n) vector from the padded layout
+        (a view where only trailing padding goes; a pad2g map or the row
+        permutation copies, counted in
+        ``repro_solve_staged_copies_total{phase="out"}``)."""
         v = np.asarray(v)
+        if self._pad2g is not None or self._row_iperm is not None:
+            _M_STAGED_COPIES.inc(phase="out")
         if self._pad2g is not None:
             out = np.zeros(v.shape[:-1] + (self.n,), self.dtype)
             valid = self._pad2g < self.n

@@ -290,6 +290,7 @@ class SolvePlan:
         self._trace_cell = trace_cell
         self.executions = 0
         self._exe = None
+        self._zero_x0 = None
         self.last_iters = None
         self.last_status = None
         self.last_bad_iter = None
@@ -345,17 +346,24 @@ class SolvePlan:
 
     def _operands(self, b, x0, vals):
         """The program's device operands for host (b, x0[, vals]), and
-        the bytes handed to the device for them (a ``vals=None`` operand
-        is the engine's resident buffer: no transfer)."""
+        the bytes handed to the device for them.  ``x0=None`` is the
+        plan's resident zero guess and ``vals=None`` the engine's resident
+        value buffer: neither is transferred."""
         eng = self.engine
-        args = (eng.to_device_vec(b), eng.to_device_vec(x0))
+        sent = [eng.to_device_vec(b)]
+        if x0 is None:
+            args = (sent[0], self._zero_x0)
+        else:
+            sent.append(eng.to_device_vec(x0))
+            args = tuple(sent)
         if self.spec.injectable:
             args += (eng.vals_operand(vals),)
+            if vals is not None:
+                sent.append(args[-1])
         elif vals is not None:
             raise ValueError(
                 "this plan closes over the matrix values as constants; "
                 "build the spec with injectable=True to pass vals per call")
-        sent = args if vals is not None else args[:2]
         return args, sum(a.nbytes for a in sent)
 
     def compile(self):
@@ -368,12 +376,19 @@ class SolvePlan:
 
         The first call runs under a ``plan_compile`` span, feeds
         ``repro_plan_compile_seconds``, and registers the executable with
-        ``repro.obs.scopes.SCOPES`` (parsed only when read)."""
+        ``repro.obs.scopes.SCOPES`` (parsed only when read).
+
+        It also places the plan's zero initial guess on the device, in the
+        program's layout and sharding; every call without an ``x0`` passes
+        that buffer and sends none.  No jit under ``repro.core`` donates an
+        argument (no ``donate_argnums``), so no execution writes into it;
+        keep it that way for this operand."""
         if self._exe is None:
             shape = ((self.engine.n,) if self.spec.batch is None
                      else (self.spec.batch, self.engine.n))
             zeros = np.zeros(shape)
-            args, _ = self._operands(zeros, zeros, None)
+            self._zero_x0 = self.engine.to_device_vec(zeros)
+            args, _ = self._operands(zeros, None, None)
             method = self.spec.method
             tr0 = self._trace_cell[0]
             t0 = _clock.now()
@@ -397,13 +412,21 @@ class SolvePlan:
         buffer for THIS call -- same shape/dtype as the engine's packed
         values; None runs the clean operator.
 
-        The call is a ``solve`` span with three children -- ``stage_in``
-        (initial guess, padding, host-to-device transfer), ``execute``
-        (dispatch to outputs ready) and ``stage_out`` (outputs back to the
-        host, x un-padded) -- and feeds ``repro_solve_stage_seconds``,
+        The call is a ``solve`` span with three children -- ``stage_in``,
+        ``execute`` (dispatch to outputs ready) and ``stage_out`` (every
+        output back to the host in one batched ``jax.device_get``, x
+        un-padded) -- and feeds ``repro_solve_stage_seconds``,
         ``repro_solve_seconds`` and the transfer byte counters.  All of it
         is host-side: the program is untouched, so instrumented solves are
-        bitwise identical to bare ones (asserted in tests/test_obs.py)."""
+        bitwise identical to bare ones (asserted in tests/test_obs.py).
+
+        ``stage_in`` sends b and, only when one is given, x0; without one
+        the plan's resident zero guess is passed and nothing is sent for
+        it.  A vector the engine's layout leaves as it is (no padding, no
+        row permutation) is transferred straight from the caller's array,
+        after one ``astype`` where its dtype differs from the engine's;
+        only a padded or permuted layout builds a host staging copy
+        (``repro_solve_staged_copies_total``)."""
         b = np.asarray(b)
         self._check(b)
         exe = self.compile()
@@ -412,9 +435,7 @@ class SolvePlan:
         with _span("solve", kind="solve", method=method):
             t0 = _clock.now()
             with _span("solve.stage_in"):
-                if x0 is None:
-                    x0 = np.zeros(b.shape)
-                else:
+                if x0 is not None:
                     x0 = np.asarray(x0)
                     if b.ndim == 2 and x0.ndim == 1:
                         # a shared (n,) initial guess for a (k, n) batch:
@@ -429,12 +450,11 @@ class SolvePlan:
             t2 = _clock.now()
             with _span("solve.stage_out"):
                 d2h = sum(a.nbytes for a in out)
-                x, norms, its, status, bad = out
-                x = eng.from_device_vec(np.asarray(x))
-                norms = np.asarray(norms)
-                self.last_iters = np.asarray(its)
-                self.last_status = np.asarray(status)
-                self.last_bad_iter = np.asarray(bad)
+                x, norms, its, status, bad = jax.device_get(out)
+                x = eng.from_device_vec(x)
+                self.last_iters = its
+                self.last_status = status
+                self.last_bad_iter = bad
             t3 = _clock.now()
         _M_EXECUTIONS.inc(method=method)
         _M_SOLVE_S.observe(t2 - t1, method=method)

@@ -11,7 +11,9 @@ instruction -> scope map, the solve's staging spans and transfer counters.
    calls a name two executables disagree on ``other``.
 3. **Spans and counters.**  A solve is a ``solve`` span whose
    ``stage_in`` / ``execute`` / ``stage_out`` children share its request
-   id; the byte counters equal the transferred arrays' ``nbytes``;
+   id; the byte counters equal the transferred arrays' ``nbytes`` (a
+   call without x0 sends b alone); ``repro_solve_staged_copies_total``
+   counts only vectors a padded or permuted layout copies on the host;
    ``SolvePlan.compile()`` feeds ``plan_compile``.
 """
 
@@ -221,12 +223,12 @@ def test_solve_stages_nest_under_solve_and_count_bytes(batch):
     stages0 = _stage_counts()
     obs.TRACER.clear()
     plan(b)
-    # what the call hands over: the padded b and x0; what it takes back:
-    # every output of the program
+    # what the call hands over: the padded b alone (with no x0 given the
+    # plan passes its resident zero guess); what it takes back: every
+    # output of the program
     args = (eng.to_device_vec(b), eng.to_device_vec(np.zeros(shape)))
     outs = plan.compile()(*args)
-    assert _counter("repro_solve_h2d_bytes_total") - h2d0 == \
-        sum(a.nbytes for a in args)
+    assert _counter("repro_solve_h2d_bytes_total") - h2d0 == args[0].nbytes
     assert _counter("repro_solve_d2h_bytes_total") - d2h0 == \
         sum(o.nbytes for o in outs)
     stages = _stage_counts()
@@ -240,6 +242,52 @@ def test_solve_stages_nest_under_solve_and_count_bytes(batch):
         assert k.parent == solve.id and k.request == solve.id
         assert solve.start <= k.start <= k.end <= solve.end
     assert kids[0].end <= kids[1].start and kids[1].end <= kids[2].start
+
+
+def _staging_engine(kind):
+    if kind == "rcm":
+        return AzulEngine(laplacian_2d(16), precond="jacobi",
+                          dtype=np.float32, reorder="rcm")
+    if kind == "padded":                     # n = 225, n_pad = 232
+        return AzulEngine(laplacian_2d(15), precond="jacobi",
+                          dtype=np.float32)
+    return _engine(kind)
+
+
+@pytest.mark.parametrize("kind", ["stored", "stencil", "rcm", "padded"])
+def test_h2d_counts_one_padded_vector_per_call_without_x0(kind):
+    eng = _staging_engine(kind)
+    plan = eng.plan(SPEC)
+    plan.compile()
+    b = np.random.default_rng(0).standard_normal(eng.n)
+    vec = eng.n_pad * np.dtype(np.float32).itemsize
+    h2d0 = _counter("repro_solve_h2d_bytes_total")
+    for _ in range(3):
+        plan(b)
+    assert _counter("repro_solve_h2d_bytes_total") - h2d0 == 3 * vec
+    plan(b, x0=np.zeros(eng.n))              # an explicit x0 is sent
+    assert _counter("repro_solve_h2d_bytes_total") - h2d0 == 5 * vec
+
+
+@pytest.mark.parametrize("kind, copies_in, copies_out", [
+    ("stored", 0, 0), ("stencil", 0, 0),
+    ("rcm", 1, 1),                           # permuted in and out
+    ("padded", 1, 0),                        # out only slices the padding
+])
+def test_staged_copies_count_padded_or_permuted_vectors(kind, copies_in,
+                                                        copies_out):
+    eng = _staging_engine(kind)
+    plan = eng.plan(SPEC)
+    plan.compile()
+    fam = obs.REGISTRY.get("repro_solve_staged_copies_total")
+    b = np.random.default_rng(0).standard_normal(eng.n)
+    in0, out0 = fam.value(phase="in"), fam.value(phase="out")
+    plan(b)
+    plan(b)
+    assert fam.value(phase="in") - in0 == 2 * copies_in
+    assert fam.value(phase="out") - out0 == 2 * copies_out
+    plan(b, x0=np.zeros(eng.n))
+    assert fam.value(phase="in") - in0 == 4 * copies_in
 
 
 def test_bridge_names_spans_repro_dot_name(monkeypatch):

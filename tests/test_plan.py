@@ -1,8 +1,13 @@
 """Plan/execute API: spec canonicalization, PlanCache hit/miss, the
 zero-recompile execution contract (including SolveServer steady state),
 the deprecated ``engine.solve(**knobs)`` shim, the bounded tolerance
-convergence trace, and registry extensibility."""
+convergence trace, registry extensibility, and stage-in without host
+zero-fills (the resident zero guess and the direct transfer give the bits
+the padded staging gave, locally and on the 2x2 halo mesh)."""
 
+import os
+import subprocess
+import sys
 import warnings
 
 import numpy as np
@@ -19,6 +24,7 @@ from repro.core import (
 )
 from repro.core.plan import _reset_deprecation_warnings
 from repro.core.registry import unregister_solver
+from repro.core.stencil import lap3d_stencil
 from repro.data.matrices import laplacian_2d
 from repro.serve import SolveServer
 
@@ -277,3 +283,139 @@ def test_register_custom_solver_runs_through_plan():
         unregister_solver("_test_richardson")
     with pytest.raises(ValueError, match="unknown solver"):
         eng.plan(SolveSpec(method="_test_richardson"))
+
+
+# -- stage-in without host zero-fills -----------------------------------------
+#
+# A call without x0 passes the plan's resident zero guess, and a vector whose
+# layout is the identity goes to the device without a padded host copy.  The
+# reference is the staging every call made before: both b and a zero x0
+# permuted and copied into zero-filled padded host buffers.
+
+
+def _staged_as_before(eng, v):
+    v = np.asarray(v)
+    if eng._row_perm is not None:
+        v = v[..., eng._row_perm]
+    out = np.zeros(v.shape[:-1] + (eng.n_pad,), eng.dtype)
+    if eng._pad2g is not None:
+        valid = eng._pad2g < eng.n
+        out[..., valid] = v[..., eng._pad2g[valid]]
+    else:
+        out[..., : eng.n] = v
+    if eng.mesh is None:
+        import jax.numpy as jnp
+
+        return jnp.asarray(out)
+    return eng._put(out, eng._bvec_spec if v.ndim == 2 else eng._vec_spec)
+
+
+def _run_as_before(plan, b):
+    """(x, norms, iters, status, bad_iter) of ``plan`` on ``b`` from a
+    zero guess, staged as every call staged them before."""
+    eng = plan.engine
+    out = plan.compile()(_staged_as_before(eng, b),
+                         _staged_as_before(eng, np.zeros(b.shape)))
+    x, *rest = (np.asarray(o) for o in out)
+    return (eng.from_device_vec(x), *rest)
+
+
+def _run(plan, b, **kw):
+    x, norms = plan(b, **kw)
+    return x, norms, plan.last_iters, plan.last_status, plan.last_bad_iter
+
+
+def _same_bits(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    return (a.dtype == b.dtype and a.shape == b.shape
+            and a.tobytes() == b.tobytes())
+
+
+@pytest.mark.parametrize("matrix, engine_kw, batch, b_dtype", [
+    ("lap2d_16", dict(dtype=np.float64), None, np.float64),   # direct
+    ("lap2d_16", dict(dtype=np.float64), 3, np.float64),      # direct, batch
+    ("lap2d_16", dict(dtype=np.float32), None, np.float64),   # one astype
+    ("lap2d_16", dict(dtype=np.float64, reorder="rcm"), None, np.float64),
+    ("lap2d_16", dict(dtype=np.float64, reorder="rcm"), 3, np.float64),
+    ("lap2d_15", dict(dtype=np.float32), None, np.float64),   # n_pad > n
+    ("lap3d_8", dict(dtype=np.float32), None, np.float32),    # stencil
+], ids=["single", "batch", "astype", "rcm", "rcm-batch", "padded",
+        "stencil"])
+def test_zero_guess_and_direct_transfer_match_padded_staging(
+        matrix, engine_kw, batch, b_dtype):
+    kind, side = matrix.rsplit("_", 1)
+    a = (laplacian_2d(int(side)) if kind == "lap2d"
+         else lap3d_stencil(int(side)))
+    eng = AzulEngine(a, precond="jacobi", **engine_kw)
+    plan = eng.plan(SolveSpec(method="pcg_tol", tol=1e-6, max_iters=300,
+                              batch=batch))
+    shape = (eng.n,) if batch is None else (batch, eng.n)
+    b = np.random.default_rng(7).standard_normal(shape).astype(b_dtype)
+    resident = _run(plan, b)
+    explicit = _run(plan, b, x0=np.zeros(shape))
+    before = _run_as_before(plan, b)
+    for got, exp, ref in zip(resident, explicit, before):
+        assert _same_bits(got, exp) and _same_bits(got, ref)
+    assert np.all(np.asarray(plan.last_status) == 0)      # converged
+
+
+def test_resident_zero_guess_stays_zero_and_b_is_not_kept():
+    eng = AzulEngine(laplacian_2d(16), precond="jacobi", dtype=np.float64)
+    plan = eng.plan(SolveSpec(method="pcg_tol", tol=1e-6, max_iters=300))
+    rng = np.random.default_rng(3)
+    b = rng.standard_normal(eng.n)
+    keep = b.copy()
+    x1, _ = plan(b)
+    x1_bits = x1.copy()
+    plan(keep, x0=x1)                        # a warm start in between
+    plan(rng.standard_normal(eng.n))
+    b[:] = 7.0                               # the caller reuses its buffer
+    x2, _ = plan(keep)
+    assert _same_bits(x2, x1_bits) and _same_bits(x1, x1_bits)
+    zero = np.asarray(plan._zero_x0)
+    assert zero.shape == (eng.n_pad,) and not zero.any()
+
+
+_DIST_STAGE_SCRIPT = """
+import numpy as np
+import jax
+jax.config.update("jax_enable_x64", True)
+from repro import obs
+from repro.core import AzulEngine, SolveSpec
+from repro.data.matrices import laplacian_2d
+from repro.launch.mesh import make_mesh
+from test_plan import _run, _run_as_before, _same_bits
+
+eng = AzulEngine(laplacian_2d(16), mesh=make_mesh((2, 2), ("data", "model")),
+                 precond="jacobi", dtype=np.float64)
+staged = obs.REGISTRY.get("repro_solve_staged_copies_total")
+for batch in (None, 3):
+    plan = eng.plan(SolveSpec(method="pcg_tol", tol=1e-6, max_iters=300,
+                              layout="halo", batch=batch))
+    assert plan.spec.layout == "halo"
+    shape = (eng.n,) if batch is None else (batch, eng.n)
+    b = np.random.default_rng(5).standard_normal(shape)
+    resident = _run(plan, b)
+    explicit = _run(plan, b, x0=np.zeros(shape))
+    before = _run_as_before(plan, b)
+    for got, exp, ref in zip(resident, explicit, before):
+        assert _same_bits(got, exp) and _same_bits(got, ref), batch
+    assert not np.asarray(plan._zero_x0).any()
+# the halo layout of this grid is the identity: nothing was staged
+assert eng._pad2g is None and eng.n_pad == eng.n
+assert staged.value(phase="in") == staged.value(phase="out") == 0
+print("STAGE_DIST_OK")
+"""
+
+
+@pytest.mark.dist
+def test_zero_guess_and_direct_transfer_match_padded_staging_2x2_halo():
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.pathsep.join(["src", "tests"])
+    r = subprocess.run(
+        [sys.executable, "-c", _DIST_STAGE_SCRIPT], capture_output=True,
+        text=True, env=env,
+        cwd=os.path.dirname(os.path.dirname(__file__)), timeout=560)
+    assert r.returncode == 0, f"stdout={r.stdout}\nstderr={r.stderr[-3000:]}"
+    assert "STAGE_DIST_OK" in r.stdout
