@@ -84,6 +84,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from . import commplan, noc, registry
 from .formats import CSR, pad_to
 from .levels import build_schedule
+from .multigrid import MGHierarchy
+from .multigrid import build as build_mg
 from .partition import (padded_layout_1d, permute_csr, plan_1d, plan_2d,
                         rcm_permutation, tile_csr)
 from ..obs import REGISTRY as _OBS
@@ -197,7 +199,10 @@ class AzulEngine:
 
     Parameters
     ----------
-    a : CSR                      square sparse matrix (host side)
+    a : CSR | Stencil | MGHierarchy
+        square sparse matrix (host side), a matrix-free stencil, or a
+        stored matrix with its multigrid levels (``repro.core.multigrid``:
+        one device, no reorder; needed by ``precond="mg"``)
     mesh : jax.sharding.Mesh | None
         None -> single-device mode (plain jnp ops; oracle/test path).
     mode : "2d" | "1d"           partition layout (2d = Azul NoC pattern)
@@ -264,6 +269,27 @@ class AzulEngine:
             raise ValueError(
                 "format must be 'auto', 'ell', 'sell', 'hyb', 'bcsr' or "
                 f"'stencil', got {format!r}")
+        hierarchy = None
+        if isinstance(a, MGHierarchy):
+            if mesh is not None:
+                raise ValueError(
+                    "an MGHierarchy runs on one device (the distributed "
+                    "partition shards one matrix, not its coarse levels)")
+            if reorder != "none":
+                raise ValueError(
+                    "reorder needs a plain CSR; the hierarchy's f2c maps "
+                    "index the operator's own row order")
+            if format == "stencil":
+                raise ValueError(
+                    "format='stencil' conflicts with an MGHierarchy: its "
+                    "levels are stored matrices")
+            hierarchy, a = a, a.levels[0]
+        needs_levels = registry.get_precond(precond).hierarchy
+        if needs_levels and hierarchy is None:
+            raise ValueError(
+                f"precond {precond!r} needs the operator's multigrid "
+                "levels: build the engine from a repro.core.multigrid."
+                "MGHierarchy (stored, one device)")
         is_stencil = isinstance(a, Stencil)
         if is_stencil:
             if mesh is not None:
@@ -316,6 +342,7 @@ class AzulEngine:
         self._compiled: dict = {}      # spmv/spmm programs (vector ops)
         self._trsv_cache: dict = {}
         self.stencil = a if is_stencil else None
+        self._mg = None                # V-cycle operands (precond="mg")
         self.format = format           # the knob; format_choice = resolved
         self.format_choice = "ell"     # per-matrix decision (local builds)
         self.format_words = None       # modeled words/matvec behind it
@@ -333,6 +360,8 @@ class AzulEngine:
                 self._build_local_stencil()
             else:
                 self._build_local()
+            if needs_levels:
+                self._mg = build_mg(hierarchy, self.dtype)
         else:
             self.pr = int(np.prod([mesh.shape[ax] for ax in self.row_axes]))
             self.pc = int(np.prod([mesh.shape[ax] for ax in self.col_axes]))
@@ -1249,13 +1278,14 @@ class AzulEngine:
     def device_bytes(self) -> int:
         """Device-resident footprint of this engine's operator state in
         bytes: matrix blocks (packed ELL cols/vals), preconditioner
-        buffers (inverse diagonal, IC(0) factor planes).  The serving
-        layer's operator registry charges this against its memory budget
-        for admission/eviction decisions.  Plan programs/executables are
+        buffers (inverse diagonal, IC(0) factor planes, multigrid
+        levels).  The serving layer's operator registry charges this
+        against its memory budget for admission/eviction decisions.  Plan programs/executables are
         not counted (they are XLA-owned and tiny next to the operands)."""
         total = 0
         seen: set[int] = set()
-        for attr in ("ell", "cols", "vals", "_dinv_pad", "_ic0", "_fmt_objs"):
+        for attr in ("ell", "cols", "vals", "_dinv_pad", "_ic0", "_fmt_objs",
+                     "_mg"):
             obj = getattr(self, attr, None)
             if obj is None:
                 continue

@@ -291,6 +291,9 @@ class SolvePlan:
         self.executions = 0
         self._exe = None
         self._zero_x0 = None
+        self._after_solve = registry.effective_precond(
+            registry.get_solver(spec.method), spec.precond,
+            engine.mode == "local").after_solve
         self.last_iters = None
         self.last_status = None
         self.last_bad_iter = None
@@ -456,6 +459,8 @@ class SolvePlan:
                 self.last_status = status
                 self.last_bad_iter = bad
             t3 = _clock.now()
+        if self._after_solve is not None:
+            self._after_solve(self.last_iters)
         _M_EXECUTIONS.inc(method=method)
         _M_SOLVE_S.observe(t2 - t1, method=method)
         _M_STAGE_S.observe(t1 - t0, phase="in")

@@ -27,6 +27,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+from .multigrid import count_vcycles, make_psolve
+
 __all__ = [
     "SolverDef",
     "PrecondDef",
@@ -143,17 +145,23 @@ class PrecondDef:
     kernels are actually dispatching (the compute-for-traffic trade of the
     whole-solve SpTRSV): with kernels inactive, ``fused="auto"``
     resolution prefers the reference apply (an explicit ``fused=True``
-    still forces the fused path).
+    still forces the fused path).  ``hierarchy`` marks preconditioners
+    that need the operator's multigrid levels (an engine built from a
+    :class:`~repro.core.multigrid.MGHierarchy`, on one device).
+    ``after_solve(iters)`` is host-side accounting a plan runs after each
+    execution with the per-RHS iteration counts.
     """
 
     name: str
     aliases: tuple = ()
     uses_dinv: bool = False
     factorized: bool = False
+    hierarchy: bool = False
     fused_local_kind: str = "fused"
     fused_shard_kind: str = "fused_shard"
     fused_local_needs_kernels: bool = False
     local_apply: Callable | None = None
+    after_solve: Callable | None = None
 
 
 # ---------------------------------------------------------------------------
@@ -491,6 +499,10 @@ def _block_ic0_apply(engine):
     return ps
 
 
+def _mg_apply(engine):
+    return make_psolve(engine._mg, engine.n, engine.n_pad)
+
+
 register_precond(PrecondDef(
     name="identity", aliases=("none",), local_apply=_identity_apply,
 ))
@@ -506,4 +518,10 @@ register_precond(PrecondDef(
     # the kernel runs (interpret mode)
     fused_local_needs_kernels=True,
     local_apply=_block_ic0_apply,
+))
+# HPCG's V-cycle with multicolour SymGS (repro.core.multigrid): one device
+# only, and in no solver's fused set, so its psolve runs unfused
+register_precond(PrecondDef(
+    name="mg", hierarchy=True, local_apply=_mg_apply,
+    after_solve=count_vcycles,
 ))

@@ -12,7 +12,8 @@ import scipy.sparse as sp
 from ..core.formats import CSR, csr_from_scipy
 
 __all__ = ["laplacian_2d", "laplacian_3d", "banded_spd", "random_spd",
-           "rmat_spd", "skew_spd", "suite", "named"]
+           "rmat_spd", "skew_spd", "hpcg_matrix", "hpcg_problem", "suite",
+           "named"]
 
 
 def laplacian_2d(nx: int, ny: int | None = None) -> CSR:
@@ -101,6 +102,60 @@ def rmat_spd(n: int, nnz_per_row: float = 8.0, seed: int = 0,
     deg = np.asarray(g.sum(axis=1)).ravel()
     lap = sp.diags(deg + 1.0) - g             # Laplacian + I: SPD
     return csr_from_scipy(lap.tocsr())
+
+
+def hpcg_matrix(nx: int, ny: int, nz: int) -> CSR:
+    """HPCG's operator (``GenerateProblem_ref``): row iz*nx*ny + iy*nx + ix
+    of an nx x ny x nz grid holds 26 on the diagonal and -1 for each of its
+    up to 26 neighbours inside the grid (no periodic wrap), columns in
+    increasing order."""
+    iz, iy, ix = np.meshgrid(np.arange(nz), np.arange(ny), np.arange(nx),
+                             indexing="ij")
+    row = (iz * ny * nx + iy * nx + ix).ravel()
+    rows, cols, vals = [], [], []
+    for dz in (-1, 0, 1):
+        for dy in (-1, 0, 1):
+            for dx in (-1, 0, 1):
+                jz, jy, jx = iz + dz, iy + dy, ix + dx
+                ok = ((jz >= 0) & (jz < nz) & (jy >= 0) & (jy < ny)
+                      & (jx >= 0) & (jx < nx)).ravel()
+                rows.append(row[ok])
+                cols.append((jz * ny * nx + jy * nx + jx).ravel()[ok])
+                vals.append(np.full(int(ok.sum()),
+                                    26.0 if dz == dy == dx == 0 else -1.0))
+    n = nx * ny * nz
+    a = sp.csr_matrix((np.concatenate(vals),
+                       (np.concatenate(rows), np.concatenate(cols))),
+                      shape=(n, n))
+    return csr_from_scipy(a)
+
+
+def hpcg_problem(nx: int, ny: int, nz: int, levels: int = 4):
+    """HPCG's multigrid problem as a :class:`~repro.core.multigrid.
+    MGHierarchy`: the operator on the nx x ny x nz grid and ``levels - 1``
+    coarse operators (``GenerateCoarseProblem``), each the 27-point operator
+    regenerated on a grid of half the extents, and the f2c maps: coarse row
+    (i, j, k) is fine row (2i, 2j, 2k).  Every extent must halve
+    ``levels - 1`` times, as HPCG requires."""
+    from ..core.multigrid import MGHierarchy
+
+    if levels < 1:
+        raise ValueError(f"levels must be >= 1, got {levels}")
+    step = 2 ** (levels - 1)
+    if any(d < step or d % step for d in (nx, ny, nz)):
+        raise ValueError(
+            f"grid {nx} x {ny} x {nz} does not halve {levels - 1} times: "
+            f"every extent must be a multiple of {step}")
+    mats, f2c = [hpcg_matrix(nx, ny, nz)], []
+    for _ in range(levels - 1):
+        cx, cy, cz = nx // 2, ny // 2, nz // 2
+        kz, ky, kx = np.meshgrid(np.arange(cz), np.arange(cy), np.arange(cx),
+                                 indexing="ij")
+        f2c.append((2 * kz * ny * nx + 2 * ky * nx + 2 * kx)
+                   .ravel().astype(np.int32))
+        nx, ny, nz = cx, cy, cz
+        mats.append(hpcg_matrix(nx, ny, nz))
+    return MGHierarchy(tuple(mats), tuple(f2c))
 
 
 def suite(scale: str = "small") -> dict[str, CSR]:

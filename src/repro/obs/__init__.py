@@ -19,7 +19,8 @@ lives in the device program, as metadata only:
   (``launch/serve.py --metrics-port``).
 * :mod:`repro.obs.scopes` -- the closed vocabulary of ``jax.named_scope``
   layers the solve programs are built under (gather, matvec, precond,
-  update, reduce, halo, control) and :data:`SCOPES`, the HLO
+  update, reduce, halo, control, and the multigrid's smooth and transfer)
+  and :data:`SCOPES`, the HLO
   instruction -> scope map of every compiled plan, which names a
   profiler trace's device operations by layer.
 

@@ -17,6 +17,10 @@ The solve programs wrap each layer boundary in :func:`scope`, a
                transpose, all-gathers, the reduce-scatter of partials)
     control    the solver loop itself: convergence test, guards, the
                per-lane freeze selects, the residual-ring write
+    smooth     the multigrid smoother: the Gauss-Seidel colour steps of
+               every level (their gathers and slice updates included)
+    transfer   the multigrid level transfers: the residual at the coarse
+               rows, the restriction and the prolongation
 
 A scope changes only HLO metadata (``op_name``), never the compiled
 program, so scopes are always on.  Where scopes nest, the innermost
@@ -42,7 +46,7 @@ __all__ = ["VOCABULARY", "OTHER", "scope", "scope_of", "parse_hlo",
            "ScopeMap", "SCOPES"]
 
 VOCABULARY = ("gather", "matvec", "precond", "update", "reduce", "halo",
-              "control")
+              "control", "smooth", "transfer")
 OTHER = "other"
 _VOCAB = frozenset(VOCABULARY)
 

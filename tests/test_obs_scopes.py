@@ -32,7 +32,7 @@ from repro.core import AzulEngine, SolveSpec
 from repro.core.stencil import lap3d_stencil
 from repro.data.matrices import laplacian_2d
 from repro.obs import scopes
-from repro.obs.scopes import OTHER, SCOPES, VOCABULARY
+from repro.obs.scopes import OTHER, VOCABULARY
 
 SPEC = SolveSpec(method="pcg_tol", tol=1e-5, max_iters=300)
 _LINE = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=]+) = .*?\b"
@@ -138,11 +138,23 @@ def test_a_name_two_executables_disagree_on_is_other():
 # -- scopes on compiled plans -------------------------------------------------
 
 
+@pytest.fixture
+def own_scopes(monkeypatch):
+    """A scope map of this test's plans alone: executables other tests of
+    the process compiled reuse instruction names with other scopes, which
+    the process-wide map calls ``other``."""
+    from repro.core import plan as plan_module
+
+    m = scopes.ScopeMap()
+    monkeypatch.setattr(plan_module, "_SCOPES", m)
+    return m
+
+
 @pytest.mark.parametrize("kind", ["stored", "stencil"])
-def test_every_instruction_of_a_plan_has_a_scope(kind):
+def test_every_instruction_of_a_plan_has_a_scope(kind, own_scopes):
     plan = _engine(kind).plan(SPEC)
     text = plan.compile().as_text()
-    found = SCOPES.mapping()
+    found = own_scopes.mapping()
     ops = _ops(text)
     assert ops
     named = {}

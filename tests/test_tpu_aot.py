@@ -172,3 +172,20 @@ def test_plan_layers_keep_their_scopes_for_v5e(kind, one_chip,
         assert ell and {found[n] for n in ell} == {"matvec"}
     else:
         assert not gathers
+
+
+def test_mg_plan_compiles_for_v5e(one_chip, compiled_kernels):
+    """HPCG's multigrid-preconditioned CG (``precond="mg"``) compiles for a
+    v5e at 16^3 with all four levels (the program unrolls the same 112
+    colour steps per V-cycle at 64^3), and its colour steps and level
+    transfers keep their ``smooth`` and ``transfer`` scopes."""
+    from repro.core import AzulEngine, SolveSpec
+    from repro.data.matrices import hpcg_problem
+    from repro.obs.scopes import parse_hlo
+
+    eng = AzulEngine(hpcg_problem(16, 16, 16), precond="mg",
+                     dtype=np.float32)
+    plan = eng.plan(SolveSpec(method="pcg_tol", tol=1e-5, max_iters=500))
+    vec = _sds(one_chip, (eng.n_pad,))
+    found = parse_hlo(plan.fn.lower(vec, vec).compile().as_text())
+    assert {"smooth", "transfer", "control"} <= set(found.values())
