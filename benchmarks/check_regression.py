@@ -33,13 +33,11 @@ machines:
   agreement with ``||b||`` and the solution agreement between the two
   recurrences (absolute thresholds).
 * **Guarded solves** (``guarded``): the fault-tolerance layer's contract.
-  Iteration counts guarded vs lean (exact), ``x_bitwise_identical`` and
-  ``collectives_match`` must stay True (guards may not change a clean
-  solve's bits nor add collectives), ``detects_indefinite`` must stay True
-  (the end-to-end detection probe), and the guarded per-iteration timing is
-  bounded BOTH against its baseline and against the SAME RUN's lean loop
-  (``--guard-overhead``, default 2x) -- the overhead of the in-loop health
-  checks is gated where it is actually measurable.
+  The iteration count, clean status and lowered collective count match the
+  baseline exactly (guards may not add collectives), ``detects_indefinite``
+  must stay True (the end-to-end detection probe), and the per-iteration
+  timing rides the timing-ratio gate.  That a clean guarded solve is
+  bitwise the bare recurrence is asserted by ``tests/test_guards.py``.
 * **Serving** (``serving``): the always-on ``SolveService`` contract per
   load point (matrix, mode, offered load / concurrency).  ``completed``,
   ``rejected`` and ``errors`` (non-converged statuses) match exactly, and
@@ -110,10 +108,8 @@ def _index(entries: list[dict], keys: tuple[str, ...]) -> dict:
 
 
 class Gate:
-    def __init__(self, timing_ratio: float, guard_overhead: float = 2.0,
-                 obs_overhead: float = 1.05):
+    def __init__(self, timing_ratio: float, obs_overhead: float = 1.05):
         self.ratio = timing_ratio
-        self.guard_overhead = guard_overhead
         self.obs_overhead = obs_overhead
         self.failures: list[str] = []
         self.checks = 0
@@ -159,9 +155,9 @@ SECTIONS = ("tol_solves", "fused_vs_unfused", "batch_sweep", "noc_plans",
 
 
 def check(cur: dict, base: dict, timing_ratio: float = 10.0,
-          guard_overhead: float = 2.0, obs_overhead: float = 1.05,
+          obs_overhead: float = 1.05,
           sections: tuple[str, ...] | None = None) -> Gate:
-    g = Gate(timing_ratio, guard_overhead, obs_overhead)
+    g = Gate(timing_ratio, obs_overhead)
     g.exact("payload", "schema", cur.get("schema"), base.get("schema"))
     want = set(SECTIONS if sections is None else sections)
 
@@ -232,19 +228,11 @@ def check(cur: dict, base: dict, timing_ratio: float = 10.0,
                                    base.get("guarded", [])):
         g.exact(where, "iters_guarded", ce.get("iters_guarded"),
                 be.get("iters_guarded"))
-        g.exact(where, "iters_unguarded", ce.get("iters_unguarded"),
-                be.get("iters_unguarded"))
-        g.exact(where, "iters_match", ce.get("iters_match"), True)
-        g.exact(where, "x_bitwise_identical",
-                ce.get("x_bitwise_identical"), True)
         g.exact(where, "status_clean", ce.get("status_clean"),
                 be.get("status_clean"))
         # zero-extra-collectives invariant: the guards read reduction slots
         # the iteration already computed, so the lowered program's
-        # collective count may not move (asserted per-payload AND pinned to
-        # the baseline's count)
-        g.exact(where, "collectives_match", ce.get("collectives_match"),
-                True)
+        # collective count is pinned to the baseline's
         g.exact(where, "collectives_guarded", ce.get("collectives_guarded"),
                 be.get("collectives_guarded"))
         g.exact(where, "detects_indefinite",
@@ -252,15 +240,6 @@ def check(cur: dict, base: dict, timing_ratio: float = 10.0,
         g.exact(where, "bad_x_finite", ce.get("bad_x_finite"), True)
         g.timing(where, "us_per_iter_guarded", ce.get("us_per_iter_guarded"),
                  be.get("us_per_iter_guarded"))
-        # guard overhead vs the lean loop, same machine/run
-        g.checks += 1
-        ug, uu = ce.get("us_per_iter_guarded"), ce.get("us_per_iter_unguarded")
-        if ug is None or uu is None:
-            g.fail(f"{where}: guarded/unguarded timing missing "
-                   f"({ug!r}, {uu!r})")
-        elif uu > 0 and ug > uu * g.guard_overhead:
-            g.fail(f"{where}: guard overhead {ug:.1f} us vs lean {uu:.1f} us "
-                   f"(> {g.guard_overhead:.1f}x)")
 
     for where, ce, be in () if _skip("pipelined") else g.section(
                                    "pipelined", ("matrix", "precond"),
@@ -305,7 +284,7 @@ def check(cur: dict, base: dict, timing_ratio: float = 10.0,
         g.exact(where, "required_families_present",
                 ce.get("required_families_present"), True)
         g.exact(where, "method", ce.get("method"), be.get("method"))
-        # overhead vs the bare arm, same machine/run (like guard_overhead)
+        # overhead vs the bare arm, same machine/run
         g.leq(where, "overhead_ratio", ce.get("overhead_ratio"),
               g.obs_overhead)
         g.timing(where, "us_per_iter_instrumented",
@@ -363,9 +342,6 @@ def main(argv=None) -> int:
     ap.add_argument("--timing-ratio", type=float, default=10.0,
                     help="allowed current/baseline timing ratio (generous: "
                          "interpret-mode CPU timings are machine-dependent)")
-    ap.add_argument("--guard-overhead", type=float, default=2.0,
-                    help="allowed guarded/lean per-iteration timing ratio "
-                         "within ONE payload (same machine, same run)")
     ap.add_argument("--obs-overhead", type=float, default=1.05,
                     help="allowed instrumented/bare per-iteration timing "
                          "ratio within ONE payload (the repro.obs always-on "
@@ -416,7 +392,6 @@ def main(argv=None) -> int:
             print(f"unknown --sections {unknown}; known: {list(SECTIONS)}")
             return 2
     g = check(cur, base, timing_ratio=args.timing_ratio,
-              guard_overhead=args.guard_overhead,
               obs_overhead=args.obs_overhead, sections=sections)
     if g.failures:
         print(f"PERF REGRESSION: {len(g.failures)} failure(s) "

@@ -1090,7 +1090,6 @@ class AzulEngine:
             return registry.SolveContext(
                 matvec=mv, psolve=psolve, dinv=dinv, substrate=sub,
                 iters=spec.iters, tol=spec.tol, max_iters=spec.max_iters,
-                guard=spec.guard,
             )
 
         if spec.injectable:
@@ -1222,7 +1221,7 @@ class AzulEngine:
             ctx = registry.SolveContext(
                 matvec=amv, psolve=ps, dinv=dinv_loc, dot=dot, dot2=dot2,
                 substrate=sub, iters=spec.iters, tol=spec.tol,
-                max_iters=spec.max_iters, guard=spec.guard,
+                max_iters=spec.max_iters,
             )
             res = ensure_status(sdef.run(ctx, b_loc, x0_loc), b_loc)
             # status/bad_iter derive from psum'd reduction slots, so they

@@ -109,12 +109,6 @@ class SolveSpec:
                decision like ``precond`` -- the matrix is repacked under
                the permutation, so a spec naming a different reorder than
                the engine was built with is rejected)
-    guard      in-loop numerical health guards (breakdown/divergence/
-               stagnation detection + structured per-RHS status; see
-               ``core.solvers``).  Default True; forced to False for
-               methods without the ``guarded`` capability.  ``guard=False``
-               on a guarded method lowers the lean pre-guard loop (the
-               A/B baseline the regression gate times against).
     injectable matrix values become a runtime program argument instead of
                a closed-over constant: ``plan(b, vals=...)`` can substitute
                a (corrupted) value buffer per call without retracing --
@@ -138,7 +132,6 @@ class SolveSpec:
     fused: Any = "auto"
     layout: str | None = None
     reorder: str | None = None
-    guard: bool = True
     injectable: bool = False
     format: str | None = None
 
@@ -200,12 +193,9 @@ def canonicalize(spec: SolveSpec, engine) -> SolveSpec:
         iters = max_iters          # one budget field: iters mirrors the cap
     else:
         tol, max_iters, iters = None, None, int(spec.iters)
-    if spec.guard not in (True, False):
-        raise ValueError(f"guard must be True or False, got {spec.guard!r}")
     if spec.injectable not in (True, False):
         raise ValueError(
             f"injectable must be True or False, got {spec.injectable!r}")
-    guard = bool(spec.guard) and sdef.guarded
     # None and 'auto' defer to the engine-level format knob, which (when
     # itself 'auto') resolved to the per-matrix autotuned decision at
     # engine build; pinned modes (dist/injectable/stencil) force theirs
@@ -225,7 +215,7 @@ def canonicalize(spec: SolveSpec, engine) -> SolveSpec:
     )
     return replace(spec, method=sdef.name, precond=pdef.name, iters=iters,
                    tol=tol, max_iters=max_iters, fused=fused, layout=layout,
-                   reorder=engine.reorder, guard=guard,
+                   reorder=engine.reorder,
                    injectable=bool(spec.injectable), format=fmt)
 
 

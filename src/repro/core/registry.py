@@ -79,7 +79,6 @@ class SolveContext:
     iters: int = 0
     tol: float | None = None
     max_iters: int | None = None
-    guard: bool = True                # in-loop numerical health guards
 
 
 @dataclass(frozen=True)
@@ -104,11 +103,7 @@ class SolverDef:
     ``comm_overlap`` marks methods whose recurrence can consume the split
     communication-hiding matvec (``matvec_start``/``matvec_finish``): on a
     halo layout the engine lowers their SpMV as interior/frontier passes
-    with the pull schedule double-buffered across iterations.  ``guarded``
-    marks methods with in-loop numerical health guards (they accept
-    ``guard`` and return a structured per-RHS ``status``/``bad_iter``;
-    canonicalization forces ``guard=False`` for methods without the
-    capability, whose programs report STATUS_UNGUARDED).  ``aliases``
+    with the pull schedule double-buffered across iterations.  ``aliases``
     are alternate spellings ``get_solver`` resolves to this entry;
     canonicalization rewrites specs to the canonical name so aliased plans
     share one cache slot.
@@ -127,7 +122,6 @@ class SolverDef:
     local_precond_override: dict = field(default_factory=dict)
     dist_precond_override: dict = field(default_factory=dict)
     comm_overlap: bool = False
-    guarded: bool = False
     formats: frozenset = _ALL_FORMATS
     aliases: tuple = ()
 
@@ -383,7 +377,7 @@ def _run_pcg(c: SolveContext, b, x0):
     from . import solvers
 
     return solvers.pcg(c.matvec, b, psolve=c.psolve, x0=x0, iters=c.iters,
-                       substrate=c.substrate, guard=c.guard, **_dot_kw(c))
+                       substrate=c.substrate, **_dot_kw(c))
 
 
 def _run_pcg_tol(c: SolveContext, b, x0):
@@ -391,14 +385,14 @@ def _run_pcg_tol(c: SolveContext, b, x0):
 
     return solvers.pcg_tol(c.matvec, b, psolve=c.psolve, x0=x0, tol=c.tol,
                            max_iters=c.max_iters, substrate=c.substrate,
-                           guard=c.guard, **_dot_kw(c))
+                           **_dot_kw(c))
 
 
 def _run_cg(c: SolveContext, b, x0):
     from . import solvers
 
     return solvers.cg(c.matvec, b, x0=x0, iters=c.iters,
-                      substrate=c.substrate, guard=c.guard, **_dot_kw(c))
+                      substrate=c.substrate, **_dot_kw(c))
 
 
 def _pipe_kw(c: SolveContext) -> dict:
@@ -413,7 +407,7 @@ def _run_pcg_pipelined(c: SolveContext, b, x0):
 
     return solvers.pcg_pipelined(c.matvec, b, psolve=c.psolve, x0=x0,
                                  iters=c.iters, substrate=c.substrate,
-                                 guard=c.guard, **_pipe_kw(c))
+                                 **_pipe_kw(c))
 
 
 def _run_pcg_pipelined_tol(c: SolveContext, b, x0):
@@ -421,8 +415,7 @@ def _run_pcg_pipelined_tol(c: SolveContext, b, x0):
 
     return solvers.pcg_pipelined_tol(c.matvec, b, psolve=c.psolve, x0=x0,
                                      tol=c.tol, max_iters=c.max_iters,
-                                     substrate=c.substrate, guard=c.guard,
-                                     **_pipe_kw(c))
+                                     substrate=c.substrate, **_pipe_kw(c))
 
 
 def _run_jacobi(c: SolveContext, b, x0):
@@ -435,31 +428,31 @@ def _run_jacobi(c: SolveContext, b, x0):
 register_solver(SolverDef(
     name="pcg", run=_run_pcg, fused_precond_apply=True,
     fused_local=_ALL_PRECONDS, fused_dist=_ALL_PRECONDS,
-    halo_dist=_ALL_PRECONDS, guarded=True,
+    halo_dist=_ALL_PRECONDS,
 ))
 register_solver(SolverDef(
     name="pcg_tol", run=_run_pcg_tol, tolerance=True,
     fused_precond_apply=True,
     fused_local=_ALL_PRECONDS, fused_dist=_ALL_PRECONDS,
-    halo_dist=_ALL_PRECONDS, guarded=True,
+    halo_dist=_ALL_PRECONDS,
 ))
 register_solver(SolverDef(
     name="cg", run=_run_cg, preconditioned=False,
     fused_local=_ALL_PRECONDS, fused_dist=_ALL_PRECONDS,
-    halo_dist=_ALL_PRECONDS, guarded=True,
+    halo_dist=_ALL_PRECONDS,
 ))
 register_solver(SolverDef(
     name="pcg_pipelined", run=_run_pcg_pipelined,
     fused_precond_apply=True,
     fused_local=_ALL_PRECONDS, fused_dist=_ALL_PRECONDS,
-    halo_dist=_ALL_PRECONDS, comm_overlap=True, guarded=True,
+    halo_dist=_ALL_PRECONDS, comm_overlap=True,
     aliases=("pcg_pipe",),      # pre-promotion spelling (PR 6 migration)
 ))
 register_solver(SolverDef(
     name="pcg_pipelined_tol", run=_run_pcg_pipelined_tol, tolerance=True,
     fused_precond_apply=True,
     fused_local=_ALL_PRECONDS, fused_dist=_ALL_PRECONDS,
-    halo_dist=_ALL_PRECONDS, comm_overlap=True, guarded=True,
+    halo_dist=_ALL_PRECONDS, comm_overlap=True,
 ))
 register_solver(SolverDef(
     name="jacobi", run=_run_jacobi, preconditioned=False, needs_dinv=True,

@@ -10,9 +10,15 @@ same code runs single-device (operators from ``spops``) and distributed
 
 All vector math is elementwise, so it is layout-oblivious: vectors may be
 full arrays or per-tile shards, as long as ``matvec``/``dot`` agree on the
-layout.  Iteration count is static (``lax.scan``) so the program lowers to a
-fixed HLO -- required for the dry-run/roofline path; ``*_tol`` variants use
-``lax.while_loop`` for tolerance-based stopping.
+layout.
+
+One loop body per recurrence: ``pcg_tol`` (standard PCG) and
+``pcg_pipelined_tol`` (Chronopoulos-Gear).  A tolerance and a fixed count
+are two stopping rules of that body, chosen at trace time: with a ``tol``
+the loop is a ``lax.while_loop`` that stops when every RHS has converged;
+with ``tol=None`` it is a counted loop (static trip count, as the
+dry-run/roofline path needs).  ``cg``, ``pcg`` and ``pcg_pipelined`` are
+the fixed-count spellings.
 
 Batched multi-RHS solves: ``b`` may be ``(n,)`` or stacked ``(k, n)``.  All
 vector updates broadcast over the leading batch axis; ``dot`` reduces the
@@ -34,19 +40,13 @@ arguments, reproducing the historical unfused op sequence exactly; the
 engine injects fused substrates (Pallas kernels locally, collective-fused
 shard substrates under ``shard_map``).
 
-Numerical health guards (``guard=True``, the default): each iteration
-inspects the reduction slots it has ALREADY computed (``rr``/``rz``/
-``denom`` for PCG, the stacked ``[gamma, delta, rr]`` for the pipelined
-recurrence) for NaN/Inf, indefiniteness (``rho <= 0`` where positivity is
-required), residual divergence, and -- in tolerance mode -- stagnation.
-Faulted RHS freeze at their last finite iterate (per-lane ``jnp.where``
-select, so a clean solve is bit-identical to ``guard=False``) and the
-result carries a structured per-RHS ``status`` plus the first bad
-iteration.  The guards add zero collectives: every test reads a slot the
-recurrence already reduced.
-
-Convergence bookkeeping (residual-norm trace) is carried through the scan so
-benchmarks can plot paper-style convergence curves without re-running.
+Numerical health guards (always on) read only reduction slots the
+recurrence has already computed, so they add no collective, and flag
+NaN/Inf, indefiniteness, divergence and -- with a tolerance --
+stagnation.  Faulted RHS freeze at their last finite iterate through
+per-lane ``jnp.where`` selects (an all-true mask keeps a clean solve's
+bits those of the bare recurrence), and the result carries a per-RHS
+``status`` and first bad iteration.
 
 Each solver runs under the ``control`` scope (``repro.obs.scopes``): the
 loop, the stopping test, the guards, the freeze selects and the residual
@@ -84,7 +84,7 @@ STATUS_MAXITER = 1       # iteration budget exhausted (or fixed-iter run)
 STATUS_BREAKDOWN = 2     # NaN/Inf or indefinite M / A (rho or pAp <= 0)
 STATUS_DIVERGED = 3      # residual grew past DIVERGENCE_FACTOR * |r0|
 STATUS_STAGNATED = 4     # no new best residual for STALL_WINDOW iterations
-STATUS_UNGUARDED = -1    # method ran without guards (jacobi, guard=False)
+STATUS_UNGUARDED = -1    # method runs without guards (jacobi)
 
 _STATUS_NAMES = {
     STATUS_CONVERGED: "converged",
@@ -211,14 +211,12 @@ def cg(
     iters: int = 100,
     dot: Dot = _default_dot,
     substrate: SolverSubstrate | None = None,
-    guard: bool = True,
 ) -> SolveResult:
-    """Conjugate gradients, fixed iteration count (scan)."""
+    """Conjugate gradients: :func:`pcg` with the identity preconditioner."""
     return pcg(matvec, b, x0=x0, iters=iters, psolve=lambda r: r, dot=dot,
-               substrate=substrate, guard=guard)
+               substrate=substrate)
 
 
-@scope("control")
 def pcg(
     matvec: MatVec,
     b: Vec,
@@ -227,9 +225,98 @@ def pcg(
     iters: int = 100,
     dot: Dot = _default_dot,
     substrate: SolverSubstrate | None = None,
-    guard: bool = True,
 ) -> SolveResult:
-    """Preconditioned CG (fixed iterations, residual trace carried).
+    """Preconditioned CG for a fixed count of ``iters`` iterations: the
+    :func:`pcg_tol` loop with no stopping test.
+
+    Every RHS runs the whole count (``iters`` reports it for each lane, a
+    faulted one too), there is no stall test, and a clean lane ends with
+    status ``maxiter`` -- a zero ``b`` included."""
+    return pcg_tol(matvec, b, psolve, x0=x0, tol=None, max_iters=iters,
+                   dot=dot, substrate=substrate)
+
+
+def _start(tol, init_bad, bnorm, act0, it0):
+    """Each lane's fault, first bad iteration and active mask before the
+    loop: a non-finite initial residual or reduction (operator or b already
+    poisoned) is a breakdown at iteration 0, not a clean run.  A fixed
+    count (``tol=None``) keeps every lane active."""
+    if tol is not None:
+        init_bad = init_bad | ~jnp.isfinite(bnorm)
+    fault0 = (jnp.where(init_bad, jnp.int32(STATUS_BREAKDOWN), jnp.int32(0))
+              + it0)
+    bad0 = jnp.where(init_bad, jnp.int32(0), jnp.int32(-1)) + it0
+    act0 = jnp.ones_like(it0, bool) if tol is None else (fault0 == 0) & act0
+    return fault0, bad0, act0
+
+
+def _tally(tol, bnorm, k, rn, rn_prev, breakdown, diverged, act, fault, bad,
+           best, since, trace):
+    """Close a step of either recurrence: the stall test (tolerance mode
+    only), each lane's first fault, the residual-ring write and the next
+    active mask.  Returns ``(good, rn_out, act, trace, fault, bad, best,
+    since)``; the step is kept on the ``good`` lanes."""
+    stalled = None
+    if tol is not None:
+        improved = rn < best
+        best = jnp.minimum(rn, best)
+        since = jnp.where(improved, 0, since + 1)
+        stalled = act & (since >= STALL_WINDOW)
+    live = fault == 0
+    hit = breakdown | diverged
+    if stalled is not None:
+        hit = hit | stalled
+    newly = live & hit
+    fault = jnp.where(newly, _fault_code(breakdown, diverged, stalled), fault)
+    bad = jnp.where(newly, k + 1, bad)
+    good = fault == 0
+    rn_out = jnp.where(good, rn, rn_prev)
+    trace = trace.at[k + 1].set(rn_out)
+    if tol is not None:
+        act = good & (rn / bnorm > tol)
+    return good, rn_out, act, trace, fault, bad, best, since
+
+
+def _loop(tol, max_iters, body, state, act_at, k_at):
+    """Run ``body`` from ``state``: with a tolerance, a while_loop until no
+    lane is active (``state[act_at]``) or ``state[k_at]`` reaches
+    ``max_iters``; with ``tol=None``, a counted loop of ``max_iters``
+    steps."""
+    if tol is None:
+        return lax.fori_loop(0, max_iters, lambda _, s: body(s), state)
+
+    def cond(state):
+        act, k = state[act_at], state[k_at]
+        return jnp.any(act) & (k < max_iters)
+
+    return lax.while_loop(cond, body, state)
+
+
+def _finish(x, trace, it, act, k, fault, bad, max_iters):
+    """The loop's result: the ring's unwritten tail filled with the final
+    residual (a flat converged tail), and each lane's status -- its fault,
+    else ``maxiter`` while still active, else ``converged``."""
+    idx = jnp.arange(max_iters + 1)
+    written = (idx <= k).reshape((-1,) + (1,) * (trace.ndim - 1))
+    trace = jnp.where(written, trace, trace[k])
+    status = jnp.where(fault != 0, fault,
+                       jnp.where(act, jnp.int32(STATUS_MAXITER),
+                                 jnp.int32(STATUS_CONVERGED)))
+    return SolveResult(x, trace, it, status, bad)
+
+
+@scope("control")
+def pcg_tol(
+    matvec: MatVec,
+    b: Vec,
+    psolve: Callable[[Vec], Vec],
+    x0: Vec | None = None,
+    tol: float | None = 1e-8,
+    max_iters: int = 1000,
+    dot: Dot = _default_dot,
+    substrate: SolverSubstrate | None = None,
+) -> SolveResult:
+    """Preconditioned CG: the one loop of the standard recurrence.
 
     This is the paper's workload: each iteration is one SpMV (matvec), one
     (or two, for IC(0)) SpTRSV (psolve), two dots and three axpys -- the
@@ -241,18 +328,28 @@ def pcg(
     ``substrate=None`` a reference substrate wraps the ``matvec``/
     ``psolve``/``dot`` arguments (the historical unfused sequence); a fused
     substrate runs the same recurrence with the denominator emitted from
-    the matrix stream and the three vector updates + two dots in one pass.
+    the matrix stream and the three vector updates + two dots in one pass
+    -- identical values, and in particular identical iteration counts.
     The loop is phrased in *folded* form: ``p = z + beta p`` executes at
     the top of the step through ``fold_matvec_dot``, so fused substrates
     can compute it at SpMV-gather time (same recurrence, same values --
-    the scan simply carries (z, beta) instead of a pre-updated p).
+    the loop carries (z, beta) instead of a pre-updated p).
 
-    With ``guard=True`` each step checks the denominators and ``rr`` it
-    already reduced (NaN/Inf, ``pAp < 0`` with ``rz > 0`` or ``rz' < 0``
-    => breakdown; residual blow-up => diverged) and freezes faulted RHS at
-    their last finite iterate; ``status``/``bad_iter`` report per RHS.
-    A clean run is bit-identical to ``guard=False``.
-    """
+    Stopping: with a ``tol`` the loop runs until every RHS has ``|r| <=
+    tol * |b|`` (or ``max_iters``), testing the ``rr`` the update already
+    reduced, and ``iters`` counts each RHS's active iterations;
+    ``tol=None`` runs exactly ``max_iters`` (see :func:`pcg`).  The trace
+    is a ring of static shape ``(max_iters + 1[, k])`` written in place
+    (slot ``i`` after iteration ``i``), filled past the stop with the
+    final residual norm.
+
+    Guards: each step checks the denominators and ``rr`` it already
+    reduced (NaN/Inf, ``pAp < 0`` with ``rz > 0`` or ``rz' < 0`` =>
+    breakdown; residual blow-up => diverged) and, with a tolerance, an
+    active lane with no new best residual for ``STALL_WINDOW`` iterations
+    stops with ``STATUS_STAGNATED``.  Faulted lanes freeze at their last
+    finite iterate (and leave the active set); ``status``/``bad_iter``
+    report per RHS."""
     sub = substrate if substrate is not None else reference_substrate(
         matvec, psolve, dot
     )
@@ -260,37 +357,21 @@ def pcg(
     r = b - sub.matvec(x)
     z = sub.psolve(r)
     rz = sub.dot(r, z)
-    r0 = _norm(sub.dot(r, r))
+    bnorm = None
+    if tol is not None:
+        bnorm = _norm(sub.dot(b, b))
+        bnorm = jnp.where(bnorm == 0, 1.0, bnorm)
     p = jnp.zeros_like(b)
     beta = jnp.zeros_like(rz)          # first fold: p = z + 0*0 = z
+    r0n = _norm(sub.dot(r, r))
+    trace0 = jnp.zeros((max_iters + 1,) + r0n.shape, r0n.dtype).at[0].set(r0n)
+    act0 = None if tol is None else r0n / bnorm > tol
+    it0 = _iters_like(b, 0)
 
-    if not guard:
-        def step(carry, _):
-            x, r, z, p, rz, beta = carry
-            p, ap, denom = sub.fold_matvec_dot(z, p, beta)
-            alpha = rz / jnp.where(denom == 0, 1.0, denom)
-            x, r, z, rr, rz_new = sub.update(alpha, x, r, p, ap)
-            beta = rz_new / jnp.where(rz == 0, 1.0, rz)
-            return (x, r, z, p, rz_new, beta), _norm(rr)
-
-        (x, r, z, p, rz, beta), norms = lax.scan(
-            step, (x, r, z, p, rz, beta), None, length=iters
-        )
-        return SolveResult(x, jnp.concatenate([r0[None], norms]),
-                           _iters_like(b, iters),
-                           _iters_like(b, STATUS_UNGUARDED),
-                           _iters_like(b, -1))
-
-    # init-time guard: a non-finite initial residual / rz (operator or b
-    # already poisoned) must not masquerade as a clean run
-    init_bad = _guard_flags(r0, rz)
-    fault0 = jnp.where(init_bad, jnp.int32(STATUS_BREAKDOWN), jnp.int32(0))
-    bad0 = jnp.where(init_bad, jnp.int32(0), jnp.int32(-1))
-    fault0 = fault0 + _iters_like(b, 0)      # broadcast to per-RHS shape
-    bad0 = bad0 + _iters_like(b, 0)
-
-    def step(carry, i):
-        x, r, z, p, rz, beta, rn_prev, fault, bad = carry
+    def body(state):
+        (x, r, z, p, rz, beta, act, it, k, trace, rn_prev, fault, bad,
+         best, since) = state
+        it = it + act.astype(jnp.int32)
         p2, ap, denom = sub.fold_matvec_dot(z, p, beta)
         alpha = rz / jnp.where(denom == 0, 1.0, denom)
         x2, r2, z2, rr, rz_new = sub.update(alpha, x, r, p2, ap)
@@ -300,29 +381,28 @@ def pcg(
         sign_bad = (((_sq(denom) < 0) & (_sq(rz) > 0))
                     | (_sq(rz_new) < 0))
         breakdown = (_guard_flags(rn, denom, rz_new)
-                     | (_sign_live(rn_prev, r0) & sign_bad))
-        diverged = rn > DIVERGENCE_FACTOR * r0
-        newly = (fault == 0) & (breakdown | diverged)
-        fault = jnp.where(newly, _fault_code(breakdown, diverged), fault)
-        bad = jnp.where(newly, (i + 1).astype(jnp.int32), bad)
-        good = fault == 0
-        rn_out = jnp.where(good, rn, rn_prev)
-        carry = (_sel(good, x2, x), _sel(good, r2, r), _sel(good, z2, z),
-                 _sel(good, p2, p), _sel(good, rz_new, rz),
-                 _sel(good, beta2, beta), rn_out, fault, bad)
-        return carry, rn_out
+                     | (_sign_live(rn_prev, r0n) & sign_bad))
+        diverged = rn > DIVERGENCE_FACTOR * r0n
+        good, rn_out, act, trace, fault, bad, best, since = _tally(
+            tol, bnorm, k, rn, rn_prev, breakdown, diverged, act, fault,
+            bad, best, since, trace)
+        return (_sel(good, x2, x), _sel(good, r2, r), _sel(good, z2, z),
+                _sel(good, p2, p), _sel(good, rz_new, rz),
+                _sel(good, beta2, beta), act, it, k + 1, trace, rn_out,
+                fault, bad, best, since)
 
-    (x, r, z, p, rz, beta, _rn, fault, bad), norms = lax.scan(
-        step, (x, r, z, p, rz, beta, r0, fault0, bad0), jnp.arange(iters)
-    )
-    status = jnp.where(fault != 0, fault, jnp.int32(STATUS_MAXITER))
-    return SolveResult(x, jnp.concatenate([r0[None], norms]),
-                       _iters_like(b, iters), status, bad)
+    fault0, bad0, act0 = _start(tol, _guard_flags(r0n, rz), bnorm, act0,
+                                it0)
+    state = _loop(tol, max_iters, body,
+                  (x, r, z, p, rz, beta, act0, it0, jnp.int32(0), trace0,
+                   r0n, fault0, bad0, r0n, it0), act_at=6, k_at=8)
+    x, act, it, k, trace = state[0], state[6], state[7], state[8], state[9]
+    fault, bad = state[11], state[12]
+    return _finish(x, trace, it, act, k, fault, bad, max_iters)
 
 
 def _pipe_ops(matvec, psolve, dot, dot2, substrate):
-    """Resolve the pipelined iteration's op bundle (shared by the fixed-
-    and tolerance-mode variants).
+    """Resolve the pipelined iteration's op bundle.
 
     Returns ``(sub, pdots, pupd, overlapped)`` where ``pdots(r, u, w)`` is
     the stacked [gamma=(r,u), delta=(w,u), rr=(r,r)] reduction -- the
@@ -377,7 +457,6 @@ def _pipe_guard(gd, rn, rn_prev, r0n):
     return breakdown, diverged
 
 
-@scope("control")
 def pcg_pipelined(
     matvec: MatVec,
     b: Vec,
@@ -387,7 +466,26 @@ def pcg_pipelined(
     dot2: Callable[..., jnp.ndarray] | None = None,
     dot: Dot = _default_dot,
     substrate: SolverSubstrate | None = None,
-    guard: bool = True,
+) -> SolveResult:
+    """Pipelined PCG for a fixed count of ``iters`` iterations: the
+    :func:`pcg_pipelined_tol` loop with no stopping test (the fixed-count
+    semantics of :func:`pcg`)."""
+    return pcg_pipelined_tol(matvec, b, psolve, x0=x0, tol=None,
+                             max_iters=iters, dot2=dot2, dot=dot,
+                             substrate=substrate)
+
+
+@scope("control")
+def pcg_pipelined_tol(
+    matvec: MatVec,
+    b: Vec,
+    psolve: Callable[[Vec], Vec],
+    x0: Vec | None = None,
+    tol: float | None = 1e-8,
+    max_iters: int = 1000,
+    dot2: Callable[..., jnp.ndarray] | None = None,
+    dot: Dot = _default_dot,
+    substrate: SolverSubstrate | None = None,
 ) -> SolveResult:
     """Chronopoulos-Gear pipelined PCG: ONE fused reduction per iteration.
 
@@ -403,7 +501,7 @@ def pcg_pipelined(
 
     Communication hiding: the matvec operand of step ``k+1`` is
     ``m = M^-1 w``, computable at the *tail* of step ``k`` with no
-    collective.  The scan therefore carries ``(m, halo)``: when the
+    collective.  The loop therefore carries ``(m, halo)``: when the
     substrate supplies the split matvec (``matvec_start``/
     ``matvec_finish``), each step issues the halo pulls for the next
     operand before returning, and the in-flight exchange overlaps the
@@ -416,9 +514,10 @@ def pcg_pipelined(
     ``substrate`` supplies kernel-backed ops including the stacked
     ``pipe_dots`` and the one-pass 8-vector ``pipe_update``.
 
-    Guards read the same stacked reduction (gamma < 0, delta < 0 with
-    gamma > 0, NaN/Inf, divergence) -- still ONE collective per iteration.
-    """
+    The stopping test reads the rr slot of the stacked reduction and the
+    guards its other slots (gamma < 0, delta < 0 with gamma > 0, NaN/Inf,
+    divergence): still ONE collective per iteration.  Stopping rules, the
+    residual ring and the statuses are :func:`pcg_tol`'s."""
     sub, pdots, pupd, overlapped = _pipe_ops(matvec, psolve, dot, dot2,
                                              substrate)
     x = jnp.zeros_like(b) if x0 is None else x0
@@ -427,155 +526,17 @@ def pcg_pipelined(
     w = sub.matvec(u)
     gd = pdots(r, u, w)            # ONE stacked reduction: [gamma, delta, rr]
     gamma, delta = gd[0], gd[1]
-    r0 = _norm(jnp.maximum(gd[2], 0.0))
-    m = sub.psolve(w)              # first matvec operand, issued pre-loop
-    h = sub.matvec_start(m) if overlapped else ()
-
-    zv = jnp.zeros_like(b)
-    state = (x, r, u, w, zv, zv, zv, zv, m, h, gamma, delta,
-             jnp.ones_like(gamma), jnp.ones_like(gamma))
-
-    if not guard:
-        def step(carry, i):
-            (x, r, u, w, z, q, s, p, m, h, gamma, delta,
-             gamma_old, alpha_old) = carry
-            nv = sub.matvec_finish(h) if overlapped else sub.matvec(m)
-            beta, alpha = _pipe_scalars(i == 0, gamma, delta,
-                                        gamma_old, alpha_old)
-            x, r, u, w, z, q, s, p = pupd(beta, alpha, x, r, u, w, z, q, s,
-                                          p, m, nv)
-            gd = pdots(r, u, w)    # the iteration's ONE collective
-            m = sub.psolve(w)      # next operand: local, so its halo
-            h = sub.matvec_start(m) if overlapped else ()  # flies over tail
-            return (x, r, u, w, z, q, s, p, m, h, gd[0], gd[1], gamma,
-                    alpha), _norm(jnp.maximum(gd[2], 0.0))
-
-        state, norms = lax.scan(step, state, jnp.arange(iters))
-        return SolveResult(state[0], jnp.concatenate([r0[None], norms]),
-                           _iters_like(b, iters),
-                           _iters_like(b, STATUS_UNGUARDED),
-                           _iters_like(b, -1))
-
-    init_bad = _guard_flags(r0, gd[0], gd[1])
-    fault0 = (jnp.where(init_bad, jnp.int32(STATUS_BREAKDOWN), jnp.int32(0))
-              + _iters_like(b, 0))
-    bad0 = (jnp.where(init_bad, jnp.int32(0), jnp.int32(-1))
-            + _iters_like(b, 0))
-    state = state + (r0, fault0, bad0)
-
-    def step(carry, i):
-        (x, r, u, w, z, q, s, p, m, h, gamma, delta, gamma_old, alpha_old,
-         rn_prev, fault, bad) = carry
-        nv = sub.matvec_finish(h) if overlapped else sub.matvec(m)
-        beta, alpha = _pipe_scalars(i == 0, gamma, delta,
-                                    gamma_old, alpha_old)
-        x2, r2, u2, w2, z2, q2, s2, p2 = pupd(beta, alpha, x, r, u, w, z, q,
-                                              s, p, m, nv)
-        gd = pdots(r2, u2, w2)     # the iteration's ONE collective
-        rn = _norm(jnp.maximum(gd[2], 0.0))
-        m2 = sub.psolve(w2)
-        h2 = sub.matvec_start(m2) if overlapped else ()
-        breakdown, diverged = _pipe_guard(gd, rn, rn_prev, r0)
-        newly = (fault == 0) & (breakdown | diverged)
-        fault = jnp.where(newly, _fault_code(breakdown, diverged), fault)
-        bad = jnp.where(newly, (i + 1).astype(jnp.int32), bad)
-        good = fault == 0
-        rn_out = jnp.where(good, rn, rn_prev)
-        carry = (_sel(good, x2, x), _sel(good, r2, r), _sel(good, u2, u),
-                 _sel(good, w2, w), _sel(good, z2, z), _sel(good, q2, q),
-                 _sel(good, s2, s), _sel(good, p2, p), _sel(good, m2, m),
-                 tuple(_sel(good, hn, ho) for hn, ho in zip(h2, h)),
-                 _sel(good, gd[0], gamma), _sel(good, gd[1], delta),
-                 _sel(good, gamma, gamma_old), _sel(good, alpha, alpha_old),
-                 rn_out, fault, bad)
-        return carry, rn_out
-
-    state, norms = lax.scan(step, state, jnp.arange(iters))
-    fault, bad = state[15], state[16]
-    status = jnp.where(fault != 0, fault, jnp.int32(STATUS_MAXITER))
-    return SolveResult(state[0], jnp.concatenate([r0[None], norms]),
-                       _iters_like(b, iters), status, bad)
-
-
-@scope("control")
-def pcg_pipelined_tol(
-    matvec: MatVec,
-    b: Vec,
-    psolve: Callable[[Vec], Vec],
-    x0: Vec | None = None,
-    tol: float = 1e-8,
-    max_iters: int = 1000,
-    dot2: Callable[..., jnp.ndarray] | None = None,
-    dot: Dot = _default_dot,
-    substrate: SolverSubstrate | None = None,
-    guard: bool = True,
-) -> SolveResult:
-    """Pipelined PCG with relative-tolerance stopping (while_loop).
-
-    Same recurrence and op bundle as :func:`pcg_pipelined`; the stopping
-    test reuses the rr slot of the iteration's single stacked reduction
-    (the true ``|r|``, same quantity ``pcg_tol`` tests), so tolerance mode
-    still has exactly ONE collective per iteration.  The bounded residual
-    ring, batched semantics, tail-fill and guard/status semantics match
-    :func:`pcg_tol`."""
-    sub, pdots, pupd, overlapped = _pipe_ops(matvec, psolve, dot, dot2,
-                                             substrate)
-    x = jnp.zeros_like(b) if x0 is None else x0
-    r = b - sub.matvec(x)
-    u = sub.psolve(r)
-    w = sub.matvec(u)
-    gd = pdots(r, u, w)
-    gamma, delta = gd[0], gd[1]
     r0n = _norm(jnp.maximum(gd[2], 0.0))
-    bnorm = _norm(jnp.maximum(sub.dot(b, b), 0.0))
-    bnorm = jnp.where(bnorm == 0, 1.0, bnorm)
-    m = sub.psolve(w)
+    bnorm = None
+    if tol is not None:
+        bnorm = _norm(jnp.maximum(sub.dot(b, b), 0.0))
+        bnorm = jnp.where(bnorm == 0, 1.0, bnorm)
+    m = sub.psolve(w)              # first matvec operand, issued pre-loop
     h = sub.matvec_start(m) if overlapped else ()
     zv = jnp.zeros_like(b)
     trace0 = jnp.zeros((max_iters + 1,) + r0n.shape, r0n.dtype).at[0].set(r0n)
-    act0 = r0n / bnorm > tol
+    act0 = None if tol is None else r0n / bnorm > tol
     it0 = _iters_like(b, 0)
-
-    if not guard:
-        def cond(state):
-            act, k = state[16], state[18]
-            return jnp.any(act) & (k < max_iters)
-
-        def body(state):
-            (x, r, u, w, z, q, s, p, m, h, gamma, delta, gamma_old,
-             alpha_old, _rn, it, act, trace, k) = state
-            it = it + act.astype(jnp.int32)
-            nv = sub.matvec_finish(h) if overlapped else sub.matvec(m)
-            beta, alpha = _pipe_scalars(k == 0, gamma, delta,
-                                        gamma_old, alpha_old)
-            x, r, u, w, z, q, s, p = pupd(beta, alpha, x, r, u, w, z, q, s,
-                                          p, m, nv)
-            gd = pdots(r, u, w)    # ONE collective; rr drives the test
-            rn = _norm(jnp.maximum(gd[2], 0.0))
-            trace = trace.at[k + 1].set(rn)
-            act = rn / bnorm > tol
-            m = sub.psolve(w)
-            h = sub.matvec_start(m) if overlapped else ()
-            return (x, r, u, w, z, q, s, p, m, h, gd[0], gd[1], gamma,
-                    alpha, rn, it, act, trace, k + 1)
-
-        state = lax.while_loop(
-            cond, body,
-            (x, r, u, w, zv, zv, zv, zv, m, h, gamma, delta,
-             jnp.ones_like(gamma), jnp.ones_like(gamma), r0n, it0, act0,
-             trace0, jnp.int32(0)),
-        )
-        x, it, trace, k = state[0], state[15], state[17], state[18]
-        idx = jnp.arange(max_iters + 1)
-        written = (idx <= k).reshape((-1,) + (1,) * (trace.ndim - 1))
-        trace = jnp.where(written, trace, trace[k])
-        return SolveResult(x, trace, it,
-                           _iters_like(b, STATUS_UNGUARDED),
-                           _iters_like(b, -1))
-
-    def cond(state):
-        act, k = state[16], state[18]
-        return jnp.any(act) & (k < max_iters)
 
     def body(state):
         (x, r, u, w, z, q, s, p, m, h, gamma, delta, gamma_old, alpha_old,
@@ -588,21 +549,12 @@ def pcg_pipelined_tol(
                                               s, p, m, nv)
         gd = pdots(r2, u2, w2)     # ONE collective; rr drives the test
         rn = _norm(jnp.maximum(gd[2], 0.0))
-        m2 = sub.psolve(w2)
-        h2 = sub.matvec_start(m2) if overlapped else ()
+        m2 = sub.psolve(w2)        # next operand: local, so its halo
+        h2 = sub.matvec_start(m2) if overlapped else ()  # flies over tail
         breakdown, diverged = _pipe_guard(gd, rn, rn_prev, r0n)
-        improved = rn < best
-        best = jnp.minimum(rn, best)
-        since = jnp.where(improved, 0, since + 1)
-        stalled = act & (since >= STALL_WINDOW)
-        newly = (fault == 0) & (breakdown | diverged | stalled)
-        fault = jnp.where(newly, _fault_code(breakdown, diverged, stalled),
-                          fault)
-        bad = jnp.where(newly, k + 1, bad)
-        good = fault == 0
-        rn_out = jnp.where(good, rn, rn_prev)
-        trace = trace.at[k + 1].set(rn_out)
-        act = good & (rn / bnorm > tol)
+        good, rn_out, act, trace, fault, bad, best, since = _tally(
+            tol, bnorm, k, rn, rn_prev, breakdown, diverged, act, fault,
+            bad, best, since, trace)
         return (_sel(good, x2, x), _sel(good, r2, r), _sel(good, u2, u),
                 _sel(good, w2, w), _sel(good, z2, z), _sel(good, q2, q),
                 _sel(good, s2, s), _sel(good, p2, p), _sel(good, m2, m),
@@ -611,170 +563,17 @@ def pcg_pipelined_tol(
                 _sel(good, gamma, gamma_old), _sel(good, alpha, alpha_old),
                 rn_out, it, act, trace, k + 1, fault, bad, best, since)
 
-    init_bad = _guard_flags(r0n, gd[0], gd[1]) | ~jnp.isfinite(bnorm)
-    fault0 = (jnp.where(init_bad, jnp.int32(STATUS_BREAKDOWN), jnp.int32(0))
-              + it0)
-    bad0 = jnp.where(init_bad, jnp.int32(0), jnp.int32(-1)) + it0
-    act0 = (fault0 == 0) & act0
-    state = lax.while_loop(
-        cond, body,
-        (x, r, u, w, zv, zv, zv, zv, m, h, gamma, delta,
-         jnp.ones_like(gamma), jnp.ones_like(gamma), r0n, it0, act0,
-         trace0, jnp.int32(0), fault0, bad0, r0n, it0),
-    )
+    fault0, bad0, act0 = _start(tol, _guard_flags(r0n, gd[0], gd[1]), bnorm,
+                                act0, it0)
+    state = _loop(tol, max_iters, body,
+                  (x, r, u, w, zv, zv, zv, zv, m, h, gamma, delta,
+                   jnp.ones_like(gamma), jnp.ones_like(gamma), r0n, it0, act0,
+                   trace0, jnp.int32(0), fault0, bad0, r0n, it0),
+                  act_at=16, k_at=18)
     x, it, act, trace, k = (state[0], state[15], state[16], state[17],
                             state[18])
     fault, bad = state[19], state[20]
-    idx = jnp.arange(max_iters + 1)
-    written = (idx <= k).reshape((-1,) + (1,) * (trace.ndim - 1))
-    trace = jnp.where(written, trace, trace[k])
-    status = jnp.where(fault != 0, fault,
-                       jnp.where(act, jnp.int32(STATUS_MAXITER),
-                                 jnp.int32(STATUS_CONVERGED)))
-    return SolveResult(x, trace, it, status, bad)
-
-
-@scope("control")
-def pcg_tol(
-    matvec: MatVec,
-    b: Vec,
-    psolve: Callable[[Vec], Vec],
-    x0: Vec | None = None,
-    tol: float = 1e-8,
-    max_iters: int = 1000,
-    dot: Dot = _default_dot,
-    substrate: SolverSubstrate | None = None,
-    guard: bool = True,
-) -> SolveResult:
-    """PCG with relative-tolerance stopping (while_loop).
-
-    The body runs the same folded, substrate-phrased recurrence as
-    :func:`pcg` -- with a fused substrate every iteration of the tolerance
-    loop is the fused hot path (in-stream denominator, one-pass update,
-    p-fold), and the stopping test reuses the ``rr`` the update already
-    produced instead of paying a fresh dot.  ``substrate=None`` composes
-    the reference substrate from the arguments: identical values, and in
-    particular *identical iteration counts*, fused vs reference.
-
-    Batched ``(k, n)`` b: the loop runs until *every* RHS meets the
-    tolerance (or max_iters); already-converged RHS keep iterating
-    harmlessly while ``iters`` records, per RHS, how many iterations it
-    was still active.
-
-    Convergence trace: the while_loop carries a *bounded* residual-norm
-    ring of static shape ``(max_iters + 1,)`` (``(max_iters + 1, k)``
-    batched) -- slot ``i`` holds the residual norm after iteration ``i``,
-    written in place as the loop runs, so tolerance-mode solves return the
-    same plottable trace as the fixed-iteration solvers at zero dynamic
-    allocation.  Slots past the stopping iteration are filled with the
-    final residual norm (``res_norms[-1]`` stays the final residual, and
-    ``iters`` marks where the real trace ends).
-
-    Guards (``guard=True``): breakdown/divergence as in :func:`pcg`, plus
-    stagnation -- an active lane with no new best residual for
-    ``STALL_WINDOW`` iterations stops with ``STATUS_STAGNATED``.  Faulted
-    lanes deactivate (the loop moves on without them) and freeze at their
-    last finite iterate."""
-    sub = substrate if substrate is not None else reference_substrate(
-        matvec, psolve, dot
-    )
-    x = jnp.zeros_like(b) if x0 is None else x0
-    r = b - sub.matvec(x)
-    z = sub.psolve(r)
-    rz = sub.dot(r, z)
-    bnorm = _norm(sub.dot(b, b))
-    bnorm = jnp.where(bnorm == 0, 1.0, bnorm)
-    p = jnp.zeros_like(b)
-    beta = jnp.zeros_like(rz)          # first fold: p = z + 0*0 = z
-    r0n = _norm(sub.dot(r, r))
-    trace0 = jnp.zeros((max_iters + 1,) + r0n.shape, r0n.dtype).at[0].set(r0n)
-    act0 = r0n / bnorm > tol
-    it0 = _iters_like(b, 0)
-
-    if not guard:
-        def cond(state):
-            act, k = state[6], state[8]
-            return jnp.any(act) & (k < max_iters)
-
-        def body(state):
-            x, r, z, p, rz, beta, act, it, k, trace = state
-            it = it + act.astype(jnp.int32)
-            p, ap, denom = sub.fold_matvec_dot(z, p, beta)
-            alpha = rz / jnp.where(denom == 0, 1.0, denom)
-            x, r, z, rr, rz_new = sub.update(alpha, x, r, p, ap)
-            beta = rz_new / jnp.where(rz == 0, 1.0, rz)
-            rn = _norm(rr)
-            trace = trace.at[k + 1].set(rn)
-            act = rn / bnorm > tol
-            return (x, r, z, p, rz_new, beta, act, it, k + 1, trace)
-
-        x, r, z, p, rz, beta, act, it, k, trace = lax.while_loop(
-            cond, body,
-            (x, r, z, p, rz, beta, act0, it0, jnp.int32(0), trace0)
-        )
-        idx = jnp.arange(max_iters + 1)
-        written = (idx <= k).reshape((-1,) + (1,) * (trace.ndim - 1))
-        trace = jnp.where(written, trace, trace[k])
-        return SolveResult(x, trace, it,
-                           _iters_like(b, STATUS_UNGUARDED),
-                           _iters_like(b, -1))
-
-    def cond(state):
-        act, k = state[6], state[8]
-        return jnp.any(act) & (k < max_iters)
-
-    def body(state):
-        (x, r, z, p, rz, beta, act, it, k, trace, rn_prev, fault, bad,
-         best, since) = state
-        it = it + act.astype(jnp.int32)
-        p2, ap, denom = sub.fold_matvec_dot(z, p, beta)
-        alpha = rz / jnp.where(denom == 0, 1.0, denom)
-        x2, r2, z2, rr, rz_new = sub.update(alpha, x, r, p2, ap)
-        beta2 = rz_new / jnp.where(rz == 0, 1.0, rz)
-        rn = _norm(rr)
-        sign_bad = (((_sq(denom) < 0) & (_sq(rz) > 0))
-                    | (_sq(rz_new) < 0))
-        breakdown = (_guard_flags(rn, denom, rz_new)
-                     | (_sign_live(rn_prev, r0n) & sign_bad))
-        diverged = rn > DIVERGENCE_FACTOR * r0n
-        improved = rn < best
-        best = jnp.minimum(rn, best)
-        since = jnp.where(improved, 0, since + 1)
-        stalled = act & (since >= STALL_WINDOW)
-        newly = (fault == 0) & (breakdown | diverged | stalled)
-        fault = jnp.where(newly, _fault_code(breakdown, diverged, stalled),
-                          fault)
-        bad = jnp.where(newly, k + 1, bad)
-        good = fault == 0
-        rn_out = jnp.where(good, rn, rn_prev)
-        trace = trace.at[k + 1].set(rn_out)
-        act = good & (rn / bnorm > tol)
-        return (_sel(good, x2, x), _sel(good, r2, r), _sel(good, z2, z),
-                _sel(good, p2, p), _sel(good, rz_new, rz),
-                _sel(good, beta2, beta), act, it, k + 1, trace, rn_out,
-                fault, bad, best, since)
-
-    init_bad = _guard_flags(r0n, rz) | ~jnp.isfinite(bnorm)
-    fault0 = (jnp.where(init_bad, jnp.int32(STATUS_BREAKDOWN), jnp.int32(0))
-              + it0)
-    bad0 = jnp.where(init_bad, jnp.int32(0), jnp.int32(-1)) + it0
-    act0g = (fault0 == 0) & act0
-    state = lax.while_loop(
-        cond, body,
-        (x, r, z, p, rz, beta, act0g, it0, jnp.int32(0), trace0, r0n,
-         fault0, bad0, r0n, it0)
-    )
-    x, act, it, k, trace = state[0], state[6], state[7], state[8], state[9]
-    fault, bad = state[11], state[12]
-    # fill the unwritten tail with the final residual: res_norms[-1] keeps
-    # meaning "final residual" and plots show a flat converged tail
-    idx = jnp.arange(max_iters + 1)
-    written = (idx <= k).reshape((-1,) + (1,) * (trace.ndim - 1))
-    trace = jnp.where(written, trace, trace[k])
-    status = jnp.where(fault != 0, fault,
-                       jnp.where(act, jnp.int32(STATUS_MAXITER),
-                                 jnp.int32(STATUS_CONVERGED)))
-    return SolveResult(x, trace, it, status, bad)
+    return _finish(x, trace, it, act, k, fault, bad, max_iters)
 
 
 @scope("control")

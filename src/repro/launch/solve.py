@@ -40,9 +40,11 @@ Fault-tolerance demo flags:
         --method pcg_tol --max-iters 400 --inject nan --inject-at 15 \
         --ft-chunk 20 --dtype float64
 
---no-guard runs the lean pre-guard loop (the A/B baseline); every run
-reports the structured solve ``status`` (converged | maxiter | breakdown |
-diverged | stagnated | unguarded) in the JSON output, with the true relative
+The in-loop numerical health guards are always on, in the one loop of
+each CG recurrence (a fixed ``--iters`` count and a ``--tol`` are two
+stopping rules of that loop).  Every run reports the structured solve
+``status`` (converged | maxiter | breakdown | diverged | stagnated |
+unguarded, the last for jacobi) in the JSON output, with the true relative
 residual ``||b - A x|| / ||b||`` computed on the host in float64, the
 plan's compile and solve seconds (host clock), and the number of Pallas
 TPU custom calls in the compiled program.
@@ -94,9 +96,6 @@ def main(argv=None):
                     help="bandwidth-reducing RCM reordering (shrinks halos)")
     ap.add_argument("--balance", default="nnz", choices=("nnz", "rows"),
                     help="row-block load balance (nnz = prefix-sum splits)")
-    ap.add_argument("--no-guard", action="store_true",
-                    help="disable in-loop numerical health guards (the "
-                         "lean pre-guard loop; status reports 'unguarded')")
     ap.add_argument("--inject", default="",
                     choices=("", "nan", "bitflip", "halo_drop",
                              "halo_perturb", "delay"),
@@ -159,8 +158,7 @@ def main(argv=None):
 
     spec = SolveSpec(method=args.method, iters=args.iters,
                      tol=args.tol, max_iters=args.max_iters,
-                     fused=fused, layout=args.layout,
-                     guard=not args.no_guard)
+                     fused=fused, layout=args.layout)
 
     if args.inject:
         # fault-injected solve through the chunked restart driver: detect,

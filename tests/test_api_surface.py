@@ -77,7 +77,7 @@ SIGNATURES = {
     "core.AzulEngine.from_device_vec": ("self", "v"),
     "core.SolveSpec.__init__": (
         "self", "method", "precond", "iters", "tol", "max_iters", "batch",
-        "fused", "layout", "reorder", "guard", "injectable", "format",
+        "fused", "layout", "reorder", "injectable", "format",
     ),
     "core.SolvePlan.__call__": ("self", "b", "x0", "vals"),
     "core.PlanCache.get": ("self", "spec", "build", "env"),

@@ -58,13 +58,11 @@ def _payload():
         }],
         "guarded": [{
             "matrix": "m", "method": "pcg_tol", "precond": "jacobi",
-            "tol": 1e-8, "iters_guarded": 30, "iters_unguarded": 30,
-            "iters_match": True, "x_bitwise_identical": True,
+            "tol": 1e-8, "iters_guarded": 30,
             "status_clean": "converged",
-            "collectives_guarded": 0, "collectives_unguarded": 0,
-            "collectives_match": True,
+            "collectives_guarded": 0,
             "detects_indefinite": True, "bad_x_finite": True,
-            "us_per_iter_guarded": 205.0, "us_per_iter_unguarded": 200.0,
+            "us_per_iter_guarded": 205.0,
         }],
         "serving": [{
             "matrix": "m", "n": 64, "method": "pcg_tol", "mode": "open",
@@ -209,25 +207,16 @@ def test_pipelined_r0_divergence_fails():
     assert any("r0_reldiff" in f for f in g.failures)
 
 
-def test_guard_bitwise_identity_break_fails():
-    """A guarded clean solve that stops being bit-identical to the lean
-    loop means the freeze-select plumbing leaked into clean lanes."""
-    cur = _payload()
-    cur["guarded"][0]["x_bitwise_identical"] = False
-    g = check(cur, _payload())
-    assert any("x_bitwise_identical" in f for f in g.failures)
-
-
 def test_guard_added_collective_fails():
     """Guards read already-reduced slots: ANY new collective in the lowered
-    guarded program is a regression of the zero-extra-collectives
-    invariant."""
+    guarded program, against the baseline's count, is a regression of the
+    zero-extra-collectives invariant."""
     cur = _payload()
     cur["guarded"][0]["collectives_guarded"] = 1
-    cur["guarded"][0]["collectives_match"] = False
     g = check(cur, _payload())
-    assert any("collectives_match" in f for f in g.failures)
-    assert any("collectives_guarded" in f for f in g.failures)
+    assert [f for f in g.failures if "collectives_guarded" in f] == [
+        "guarded['m', 'pcg_tol']: collectives_guarded changed 0 -> 1 "
+        "(exact-match field)"]
 
 
 def test_guard_detection_loss_fails():
@@ -235,17 +224,6 @@ def test_guard_detection_loss_fails():
     cur["guarded"][0]["detects_indefinite"] = False
     g = check(cur, _payload())
     assert any("detects_indefinite" in f for f in g.failures)
-
-
-def test_guard_overhead_beyond_ratio_fails():
-    """Guarded timing is bounded against the SAME RUN's lean loop --
-    cross-machine noise cancels, so the ratio can be tight."""
-    cur = _payload()
-    cur["guarded"][0]["us_per_iter_guarded"] = 500.0
-    g = check(cur, _payload(), guard_overhead=2.0)
-    assert any("guard overhead" in f for f in g.failures)
-    cur["guarded"][0]["us_per_iter_guarded"] = 300.0
-    assert not check(cur, _payload(), guard_overhead=2.0).failures
 
 
 def test_overlap_model_drift_fails():
@@ -449,9 +427,8 @@ def test_committed_baseline_is_selfconsistent():
     assert base["pipelined"], "baseline must pin the pipelined-PCG record"
     assert base["guarded"], "baseline must pin the guarded-solve record"
     for e in base["guarded"]:
-        assert e["iters_match"] is True
-        assert e["x_bitwise_identical"] is True
-        assert e["collectives_match"] is True
+        assert e["status_clean"] == "converged"
+        assert e["collectives_guarded"] == 0
         assert e["detects_indefinite"] is True
     for e in base["pipelined"]:
         assert e["reductions_per_iter_pipelined"] == 1

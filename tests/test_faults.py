@@ -2,7 +2,7 @@
 injected faults detected through the guarded/injectable plans, the chunked
 SolveRestartManager reconverging after rollback, checkpoint corruption
 recovery, and the deadline/degradation serving paths.  The distributed half
-(halo faults + HLO collective-count identity) runs in a subprocess on a
+(halo faults + HLO collective counts) runs in a subprocess on a
 forced host-device mesh."""
 
 import os
@@ -390,9 +390,9 @@ for method in ("pcg_tol", "pcg_pipelined_tol"):
                     or rel_true > 100.0 * max(rel_claim, 1e-8))
         assert detected, (method, kind, sname, rel_claim, rel_true)
 
-# guards and injectable value operands add ZERO collectives: guarded and
-# unguarded halo programs carry identical all_reduce / collective_permute
-# counts, and the PR 6 invariants (pipelined ar==2, pcg ar==4) still hold
+# the guards read reduction slots the recurrence already has: the halo
+# programs keep the collective counts of the bare recurrence (pipelined
+# ar==2, pcg ar==4) and no all-gather
 def collectives(plan):
     ops = plan.hlo_summary()["count_by_op"]
     return (int(ops.get("all-reduce", 0)),
@@ -401,10 +401,7 @@ def collectives(plan):
 
 for method, want_ar in (("pcg_pipelined", 2), ("pcg", 4)):
     cg = collectives(eng.plan(SolveSpec(method=method, iters=60,
-                                        layout="halo", guard=True)))
-    cu = collectives(eng.plan(SolveSpec(method=method, iters=60,
-                                        layout="halo", guard=False)))
-    assert cg == cu, (method, "guard added collectives", cg, cu)
+                                        layout="halo")))
     assert cg[0] == want_ar, (method, cg)
     assert cg[2] == 0, (method, "all_gather crept in")
 

@@ -77,7 +77,7 @@ def test_engine_stencil_guard_and_spmv():
     st = lap3d_stencil(5)
     eng = AzulEngine(st, mesh=None, precond="none", dtype=np.float64)
     b = np.random.default_rng(4).standard_normal(st.n)
-    p = eng.plan(SolveSpec(method="pcg_tol", tol=1e-8, iters=300, guard=True))
+    p = eng.plan(SolveSpec(method="pcg_tol", tol=1e-8, iters=300))
     x, _ = p(b)
     assert p.last_status_names == "converged"
     a = _as_scipy(laplacian_3d(5))
